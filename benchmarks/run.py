@@ -50,6 +50,8 @@ def main() -> None:
     ap.add_argument("--json", default="",
                     help="dump {name: {us_per_call, derived}} to this path")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

@@ -153,6 +153,9 @@ class SimResult:
     retrans_bytes: Optional[np.ndarray] = None
     goodput_steps: Optional[np.ndarray] = None
     stalled_steps: Optional[np.ndarray] = None
+    # Scan chunks the adaptive horizon executed (execution bookkeeping,
+    # never a result: early exit is bit-identical to the full horizon).
+    chunks: int = 0
 
     @property
     def throughput_per_flow(self) -> np.ndarray:
@@ -915,6 +918,7 @@ def _to_result(size: np.ndarray, final, cfg: SimConfig,
                        else np.asarray(final["goodput_t"])),
         stalled_steps=(None if "stalled_t" not in final
                        else np.asarray(final["stalled_t"])),
+        chunks=int(final.get("horizon_chunks", 0)),
     )
 
 

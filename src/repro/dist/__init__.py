@@ -18,12 +18,11 @@ many near-disjoint physical paths):
   spread, so mesh placement and the roofline can quantify the paper's
   claim on this system's own traffic.
 
-Importing any submodule installs the small jax compatibility shims in
-:mod:`repro.dist.compat` (``jax.shard_map`` / ``jax.lax.axis_size`` on
-older jax), so test programs and callers can use the modern spellings.
+Importing any submodule applies :mod:`repro.dist.compat`'s CPU pin for
+forced host devices.
 """
 
-from . import compat  # noqa: F401  (installs jax shims on import)
+from . import compat  # noqa: F401  (pins the CPU under forced host devices)
 
 compat.install()
 

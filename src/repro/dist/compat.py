@@ -1,12 +1,13 @@
-"""Small forward-compat shims over the installed jax.
+"""The CPU pin for forced host devices.
 
-The repo's code and tests use the modern spellings ``jax.shard_map(f,
-mesh=..., in_specs=..., out_specs=..., check_vma=...)`` and
-``jax.lax.axis_size(name)``.  On older jax (e.g. 0.4.x) ``shard_map``
-lives in ``jax.experimental.shard_map`` with a ``check_rep`` keyword and
-``axis_size`` does not exist.  ``install()`` patches the missing names
-onto the jax namespace; it is idempotent and never overrides a native
-implementation.
+Forcing host-platform devices
+(``XLA_FLAGS=--xla_force_host_platform_device_count=N``) is a CPU-only
+mode: the multi-device tests and the 512-device dry-run use it to
+rehearse meshes without accelerators.  ``install()`` pins the jax
+platform to the CPU whenever that flag is set and the caller has not
+chosen a platform; otherwise a machine with libtpu installed but no TPU
+attached burns minutes probing the TPU backend before falling back to
+the CPU.  It is idempotent.
 """
 
 from __future__ import annotations
@@ -18,20 +19,10 @@ import jax
 __all__ = ["install"]
 
 
-def _axis_size(axis_name) -> int:
-    # psum of a literal constant-folds to the (static) named-axis size and
-    # accepts a tuple of names (returns the product).
-    return jax.lax.psum(1, axis_name)
-
-
 def install() -> None:
-    # Forcing host-platform devices is a CPU-only debugging mode (the
-    # multi-device tests and the 512-device dry-run).  Pin the platform
-    # accordingly when the caller has not chosen one: otherwise a machine
-    # with libtpu installed but no TPU attached burns minutes probing the
-    # TPU backend before falling back to CPU.  jax snapshots JAX_PLATFORMS
-    # at import, so update the live config too (no-op if the backend is
-    # already initialized — then the choice was made before us anyway).
+    # jax snapshots JAX_PLATFORMS at import, so update the live config
+    # too (no-op if the backend is already initialized — then the choice
+    # was made before us anyway).
     if ("--xla_force_host_platform_device_count"
             in os.environ.get("XLA_FLAGS", "")
             and not os.environ.get("JAX_PLATFORMS")):
@@ -41,21 +32,3 @@ def install() -> None:
                 jax.config.update("jax_platforms", "cpu")
         except Exception:
             pass
-
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-                      check_rep=None, **kw):
-            if check_rep is None:
-                # check_vma is the modern name for check_rep; default both
-                # off — the replication checker predates several collectives
-                # used here (layered ppermute chains, fixed-capacity a2a).
-                check_rep = bool(check_vma) if check_vma is not None else False
-            return _shard_map(f, mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_rep, **kw)
-
-        jax.shard_map = shard_map
-
-    if not hasattr(jax.lax, "axis_size"):
-        jax.lax.axis_size = _axis_size

@@ -205,17 +205,14 @@ class Runtime:
 
 def host_device_runtime(devices: Optional[int] = None,
                         axis: str = "data") -> Runtime:
-    """A :class:`Runtime` over a 1-D mesh of ``devices`` local devices —
-    the entry point for CPU-hosted data parallelism under
+    """A :class:`Runtime` over a 1-D mesh of ``devices`` local devices:
+    the chips of an accelerator host, or forced host CPU devices under
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 
     * ``devices`` ``None`` -> use every visible device;
     * ``devices <= 1``     -> ``Runtime(mesh=None)`` (single-device
       no-op degradation, same program, no shard_map);
-    * asking for more devices than jax can see raises with the exact
-      ``XLA_FLAGS`` incantation — the flag must be set *before* the
-      first jax import of the process, it cannot be retrofitted (the
-      experiments CLI sets it for you when run with ``--devices N``).
+    * asking for more devices than jax can see raises.
     """
     avail = jax.device_count()
     n = avail if devices is None else int(devices)
@@ -223,11 +220,13 @@ def host_device_runtime(devices: Optional[int] = None,
         return Runtime(mesh=None, data_axes=(axis,))
     if n > avail:
         raise RuntimeError(
-            f"asked for {n} devices but jax sees {avail}.  Forced host "
-            f"devices must be configured before jax initializes: set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            f"(and JAX_PLATFORMS=cpu) in the environment, or launch via "
-            f"`python -m repro.experiments sweep --devices {n}` which "
-            f"sets both before importing jax.")
+            f"asked for {n} devices but jax sees {avail} "
+            f"({jax.default_backend()}).  On an accelerator host, ask for "
+            f"at most the chips it has.  To rehearse on the CPU, set "
+            f"JAX_PLATFORMS=cpu and "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
+            f"jax initializes, or launch via "
+            f"`JAX_PLATFORMS=cpu python -m repro.experiments sweep "
+            f"--devices {n}`, which sets the flag for you.")
     mesh = Mesh(np.asarray(jax.devices()[:n]), (axis,))
     return Runtime(mesh=mesh, data_axes=(axis,))

@@ -14,11 +14,13 @@
 
 ``--quick`` shortens transport simulations (steps=400) unless a spec
 pins ``steps`` explicitly.  ``--devices N`` runs the grid through the
-distributed batch engine (repro.experiments.dist_sweep): when no device
-configuration exists yet, the CLI sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (and pins
-``JAX_PLATFORMS=cpu``) BEFORE importing jax, so forced host devices
-just work; per-cell results are identical for every device count.
+distributed batch engine (repro.experiments.dist_sweep) over N devices:
+the host's accelerator chips, or — when the caller chose the CPU with
+``JAX_PLATFORMS=cpu`` — N forced host devices (the CLI sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before importing
+jax); per-cell results are identical for every device count.
+Commands that compile keep jax's persistent compilation cache where
+:func:`repro.compile_cache.use_compile_cache` says.
 ``--checkpoint DIR`` makes a sweep resumable: completed cells are
 committed per-cell and a re-run skips them.  One sweep invocation over
 the defaults reproduces the paper's Fig 14/15-style topology x scheme x
@@ -39,25 +41,24 @@ _FORCE_FLAG = "--xla_force_host_platform_device_count"
 
 
 def _ensure_devices(n) -> None:
-    """Arrange for ``n`` visible devices before jax initializes.
+    """Arrange for ``n`` forced host CPU devices before jax initializes,
+    when the caller chose the CPU (``JAX_PLATFORMS=cpu``).  Any other
+    platform choice — including none, which lets jax pick the host's
+    accelerator — is left alone, so ``--devices N`` on an accelerator
+    host uses its chips.
 
     Forced host devices can only be configured via XLA_FLAGS before the
     first jax import; once jax is loaded this is a no-op and
     ``host_device_runtime`` raises its actionable error instead.  A
     pre-existing force flag (e.g. a CI job exporting XLA_FLAGS itself)
-    is never second-guessed, and an existing JAX_PLATFORMS choice is
-    preserved (it selects the platform, not the device count)."""
-    if not n or int(n) <= 1 or "jax" in sys.modules:
+    is never second-guessed."""
+    if (not n or int(n) <= 1 or "jax" in sys.modules
+            or os.environ.get("JAX_PLATFORMS", "") != "cpu"):
         return
     xf = os.environ.get("XLA_FLAGS", "")
     if _FORCE_FLAG not in xf:
         os.environ["XLA_FLAGS"] = (f"{xf} " if xf else "") + \
             f"{_FORCE_FLAG}={int(n)}"
-    # Pin the platform even when the caller exported the force flag
-    # themselves: forced host devices are a CPU-platform mode, and on a
-    # machine whose auto-selected platform is not cpu the flag would be
-    # inert (same pin repro.dist.compat / sitecustomize apply).
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def _quicken(evaluators, quick: bool):
@@ -143,7 +144,6 @@ def _watchdog_sweep(session, cells, args, stream) -> int:
 
 
 def cmd_sweep(args) -> int:
-    _ensure_devices(args.devices)
     from .results import results_to_json, summary_table
     from .session import Session
     from .specs import split_spec_list
@@ -269,8 +269,8 @@ def main(argv=None) -> int:
     sw.add_argument("--json", default="", help="write RunResult list here")
     sw.add_argument("--devices", type=int, default=None,
                     help="run the distributed batch engine over N devices "
-                         "(forces N host CPU devices when nothing else "
-                         "configures jax)")
+                         "(the host's chips; N forced host CPU devices "
+                         "under JAX_PLATFORMS=cpu)")
     sw.add_argument("--checkpoint", default="",
                     help="resumable sweep: per-cell checkpoint directory")
     sw.add_argument("--cell-timeout-s", type=float, default=None,
@@ -306,6 +306,10 @@ def main(argv=None) -> int:
     ls.set_defaults(fn=cmd_list)
 
     args = ap.parse_args(argv)
+    if args.cmd in ("sweep", "run"):
+        _ensure_devices(getattr(args, "devices", None))
+        from ..compile_cache import use_compile_cache
+        use_compile_cache()
     from .specs import SpecError
     try:
         return args.fn(args)

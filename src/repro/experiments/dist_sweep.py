@@ -45,14 +45,15 @@ and a re-run loads completed cells instead of re-simulating them.
 Non-transport evaluators (``mat``, ``fabric``) fall back to the
 sequential path within the same sweep and share its checkpointing.
 
-Graceful degradation (PR 8): a bucket whose compile or execution fails
-— a Pallas lowering/runtime error on an exotic shape, say — is retried
-ONCE with every cell forced onto the ``ref`` kernel backend; if the
-retry fails too, the bucket's cells are emitted with empty metrics and
-a structured ``error`` meta field instead of poisoning the whole
-artifact.  Cells whose simulation state comes back non-finite (inf/NaN
-delivered bytes) are quarantined the same way.  Error cells are NEVER
-checkpointed, so a later resume re-attempts exactly them.
+Quarantine: a bucket whose compile or execution fails — a Pallas
+lowering/runtime error on an exotic shape, say — emits its cells with
+empty metrics and a structured ``error`` meta field instead of poisoning
+the whole artifact.  There is no retry on another kernel backend: a
+failure of the measured path shows up as that failure, never as the
+oracle's numbers under the cell's name.  Cells whose simulation state
+comes back non-finite (inf/NaN delivered bytes) are quarantined the same
+way.  Error cells are NEVER checkpointed, so a later resume re-attempts
+exactly them.
 
 Emission is streamed (``callback`` fires as each cell completes,
 bucket-by-bucket) but the returned list — and therefore every sweep
@@ -65,7 +66,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,7 +75,8 @@ import numpy as np
 from ..ckpt.sweep import SweepCheckpoint
 from ..core import transport as transport_mod
 from ..dist.sharding import P, Runtime, host_device_runtime
-from .catalog import EVALUATORS, fct_metrics, transport_meta, transport_plan
+from .catalog import (EVALUATORS, fct_metrics, sim_meta, transport_meta,
+                      transport_plan)
 from .results import RunResult, order_results
 from .session import ResolvedCell, Session
 from .specs import ExperimentSpec
@@ -295,15 +297,11 @@ def _dispatch_bucket(works: List[_Work], rt: Runtime, bucket_index: int):
 
 
 def _finalize_bucket(works: List[_Work], finals, elements
-                     ) -> Tuple[Dict[int, list], Dict[int, int]]:
+                     ) -> Dict[int, list]:
     """Block on one bucket's device results and split them back into
     per-cell, per-seed :class:`SimResult`s (padding stripped).  Nested
     seed batches come back as (C, S, ...) leaves; flattening them
-    cell-major matches the flat ``elements`` order exactly.
-
-    Also returns each cell's executed chunk count (the adaptive
-    horizon's early-exit depth, max over its sim seeds) — execution
-    bookkeeping for the sweep meta, never part of the results."""
+    cell-major matches the flat ``elements`` order exactly."""
     elements, nested = elements
     n_elem = len(elements)
 
@@ -316,15 +314,13 @@ def _finalize_bucket(works: List[_Work], finals, elements
     finals = {k: flat(v)
               for k, v in jax.block_until_ready(finals).items()}
     sims: Dict[int, list] = {wi: [] for wi in range(len(works))}
-    chunks: Dict[int, int] = {wi: 0 for wi in range(len(works))}
     for i, (wi, s) in enumerate(elements):
         w = works[wi]
         sims[wi].append(transport_mod.batch_result(
             w.size, {k: v[i] for k, v in finals.items()},
             dataclasses.replace(w.cfg, seed=s), n_flows=w.n_flows,
             start=w.start))
-        chunks[wi] = max(chunks[wi], int(finals["horizon_chunks"][i]))
-    return sims, chunks
+    return sims
 
 
 def dist_sweep(session: Session, cells: List[ExperimentSpec], *,
@@ -420,44 +416,19 @@ def dist_sweep(session: Session, cells: List[ExperimentSpec], *,
             extra_meta={"sweep_bucket": bi, "error": error},
             post=w.post), persist=False))
 
-    def finalize(bi, works, finals, desc, t_disp, retried: bool):
-        # One-shot graceful degradation: a failed compile/execute is
-        # retried with every cell forced onto the ref kernel backend
-        # (a fresh bucket program — the SimConfig is jit-static); a
-        # second failure quarantines the bucket's cells.
+    def quarantine(bi: int, works: List[_Work], e: Exception):
+        say(f"# bucket {bi}: batched execution failed "
+            f"({type(e).__name__}); quarantining {len(works)} cell(s)")
+        for w in works:
+            emit_error(bi, w, {"type": "bucket_failure",
+                               "exception": type(e).__name__,
+                               "message": str(e)[:500]})
+
+    def finalize(bi, works, finals, desc, t_disp):
         try:
-            sims, chunks = _finalize_bucket(works, finals, desc)
+            sims = _finalize_bucket(works, finals, desc)
         except Exception as e:                      # noqa: BLE001
-            if retried:
-                say(f"# bucket {bi}: ref-backend retry failed too "
-                    f"({type(e).__name__}); quarantining "
-                    f"{len(works)} cell(s)")
-                for w in works:
-                    emit_error(bi, w, {
-                        "type": "bucket_failure", "retried_ref": True,
-                        "exception": type(e).__name__,
-                        "message": str(e)[:500]})
-                return
-            say(f"# bucket {bi}: batched execution failed "
-                f"({type(e).__name__}); retrying once on the "
-                "ref kernel backend")
-            for w in works:
-                w.cfg = dataclasses.replace(w.cfg, kernel_backend="ref")
-            t2 = time.perf_counter()
-            try:
-                finals2, desc2, _mode, _pads = _dispatch_bucket(
-                    works, rt, bi)
-            except Exception as e2:                 # noqa: BLE001
-                say(f"# bucket {bi}: ref-backend retry failed too "
-                    f"({type(e2).__name__}); quarantining "
-                    f"{len(works)} cell(s)")
-                for w in works:
-                    emit_error(bi, w, {
-                        "type": "bucket_failure", "retried_ref": True,
-                        "exception": type(e2).__name__,
-                        "message": str(e2)[:500]})
-                return
-            finalize(bi, works, finals2, desc2, t2, retried=True)
+            quarantine(bi, works, e)
             return
         bucket_wall = time.perf_counter() - t_disp
         for wi, w in enumerate(works):
@@ -475,49 +446,26 @@ def dist_sweep(session: Session, cells: List[ExperimentSpec], *,
                                                 / max(1, len(desc[0])))
             results.append(emit(session.finish_result(
                 w.spec, w.cell, metrics, w.ev_meta, w.pre, wall,
-                extra_meta={"sweep_bucket": bi,
-                            # adaptive-horizon early-exit depth: how many
-                            # full scan chunks ran (execution meta — the
-                            # sequential engine legitimately omits it).
-                            "sweep_chunks": chunks[wi]}, post=w.post)))
-
-    def finalize_oldest():
-        bi, works, finals, desc, t_disp = in_flight.pop(0)
-        finalize(bi, works, finals, desc, t_disp, retried=False)
+                extra_meta={"sweep_bucket": bi, **sim_meta(sims[wi])},
+                post=w.post)))
 
     for bi, works in enumerate(buckets.values()):
         t_disp = time.perf_counter()
+        n_buckets += 1
         try:
             finals, desc, mode, (nf, ne, nh) = _dispatch_bucket(works, rt,
                                                                 bi)
         except Exception as e:                      # noqa: BLE001
-            say(f"# bucket {bi}: dispatch failed ({type(e).__name__}); "
-                "retrying once on the ref kernel backend")
-            for w in works:
-                w.cfg = dataclasses.replace(w.cfg, kernel_backend="ref")
-            try:
-                finals, desc, mode, (nf, ne, nh) = _dispatch_bucket(
-                    works, rt, bi)
-            except Exception as e2:                 # noqa: BLE001
-                say(f"# bucket {bi}: ref-backend retry failed too "
-                    f"({type(e2).__name__}); quarantining "
-                    f"{len(works)} cell(s)")
-                for w in works:
-                    emit_error(bi, w, {
-                        "type": "bucket_failure", "retried_ref": True,
-                        "exception": type(e2).__name__,
-                        "message": str(e2)[:500]})
-                n_buckets += 1
-                continue
+            quarantine(bi, works, e)
+            continue
         say(f"# bucket {bi}: {len(works)} cells x seeds = {len(desc[0])} "
             f"programs via {mode}, padded to F={nf} E={ne} H={nh}")
         in_flight.append((bi, works, finals, desc, t_disp))
-        n_buckets += 1
         n_elems += len(desc[0])
         while len(in_flight) > max_in_flight:
-            finalize_oldest()
+            finalize(*in_flight.pop(0))
     while in_flight:
-        finalize_oldest()
+        finalize(*in_flight.pop(0))
     if n_buckets:
         say(f"# {n_buckets} bucket(s), {n_elems} batched programs, "
             f"simulate wall {time.perf_counter() - t_sim:.2f}s "

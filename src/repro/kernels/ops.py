@@ -1,17 +1,16 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on TPU
-deployments set ``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to
-compile the Mosaic kernels.
+``interpret=None`` resolves through :func:`repro.kernels.interpret_default`:
+compiled Mosaic kernels on TPU, interpret mode elsewhere.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax.numpy as jnp
 
+from . import interpret_default
 from .flash_attention import flash_attention
 from .gfmm import GF_P_F32, GF_P_INT32, gf_matmul
 from .pathcount import SAT, pathcount_matmul
@@ -20,19 +19,14 @@ __all__ = ["path_counts_power", "gf_power_sum", "attention", "SAT",
            "GF_P_INT32", "GF_P_F32"]
 
 
-def _interp(flag: Optional[bool]) -> bool:
-    if flag is not None:
-        return flag
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
-
 def path_counts_power(adj: jnp.ndarray, l: int, *,
                       interpret: Optional[bool] = None) -> jnp.ndarray:
     """A^l walk counts via the pathcount kernel (Theorem 1)."""
     a = adj.astype(jnp.float32)
     out = a
     for _ in range(l - 1):
-        out = pathcount_matmul(out, a, interpret=_interp(interpret))
+        out = pathcount_matmul(out, a,
+                               interpret=interpret_default(interpret))
     return out
 
 
@@ -45,7 +39,8 @@ def gf_power_sum(k_mat: jnp.ndarray, l: int, p: int = GF_P_INT32,
     eye = jnp.eye(e, dtype=jnp.int32)
     m = eye
     for _ in range(l - 1):
-        m = gf_matmul(m, k_mat, p=p, mode=mode, interpret=_interp(interpret))
+        m = gf_matmul(m, k_mat, p=p, mode=mode,
+                      interpret=interpret_default(interpret))
         m = (m + eye) % p
     return m
 
@@ -57,4 +52,4 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     """Flash attention (GQA/causal/window/softcap); see kernel docstring."""
     return flash_attention(q, k, v, causal=causal, window=window,
                            softcap=softcap, scale=scale, bq=bq, bk=bk,
-                           interpret=_interp(interpret))
+                           interpret=interpret_default(interpret))

@@ -58,32 +58,33 @@ def default_backend() -> str:
     return kernel_backend()
 
 
-_interp = interpret_default
-
-
 # -----------------------------------------------------------------------------
 # The kernel.
 # -----------------------------------------------------------------------------
+def combine_tile(acc, a, b, *, semiring: str, sat: float, bk: int):
+    """``acc ⊕ (a ⊗ b)`` for one (bm, bk) x (bk, bn) tile pair — the
+    combine shared by the dense and block-sparse kernels."""
+    if semiring in ("count", "bool"):
+        ceil = 1.0 if semiring == "bool" else sat
+        prod = jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        return jnp.minimum(acc + prod, ceil)
+    # minplus: VPU broadcast-min over the K tile.  K is walked with
+    # STATIC slices: a dynamic column slice ``a[:, k]`` is a dynamic
+    # lane slice, which Mosaic cannot lower.
+    for k in range(bk):
+        acc = jnp.minimum(acc, a[:, k:k + 1] + b[k:k + 1, :])
+    return acc
+
+
 def _semiring_kernel(a_ref, b_ref, o_ref, *, semiring: str, sat: float,
                      bk: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, _ZERO[semiring])
 
-    if semiring in ("count", "bool"):
-        ceil = 1.0 if semiring == "bool" else sat
-        prod = jax.lax.dot_general(
-            a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_ref[...] = jnp.minimum(o_ref[...] + prod, ceil)
-    else:  # minplus: VPU broadcast-min over the K tile
-        a = a_ref[...]
-        b = b_ref[...]
-
-        def body(k, acc):
-            return jnp.minimum(acc, a[:, k][:, None] + b[k, :][None, :])
-
-        o_ref[...] = jax.lax.fori_loop(0, bk, body, o_ref[...])
+    o_ref[...] = combine_tile(o_ref[...], a_ref[...], b_ref[...],
+                              semiring=semiring, sat=sat, bk=bk)
 
 
 @functools.partial(jax.jit,
@@ -138,7 +139,8 @@ def semiring_matmul(a: jnp.ndarray, b: jnp.ndarray, semiring: str = "count",
     if backend == "ref":
         return ref.semiring_matmul_ref(a, b, semiring, sat=sat)
     fn = functools.partial(_pallas_matmul, semiring=semiring, bm=bm, bn=bn,
-                           bk=bk, sat=sat, interpret=_interp(interpret))
+                           bk=bk, sat=sat,
+                           interpret=interpret_default(interpret))
     if a.ndim == 3 or b.ndim == 3:
         if a.ndim == 2:
             a = jnp.broadcast_to(a[None], (b.shape[0],) + a.shape)

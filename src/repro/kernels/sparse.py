@@ -30,13 +30,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret_default, kernel_backend, ref
-from .semiring import SAT, SEMIRINGS, _ZERO
+from .semiring import SAT, SEMIRINGS, _ZERO, combine_tile
 
 __all__ = ["sparse_semiring_matmul", "tile_occupancy"]
-
-_interp = interpret_default
 
 
 def tile_occupancy(x: jnp.ndarray, bm: int, bk: int,
@@ -59,33 +58,23 @@ def tile_occupancy(x: jnp.ndarray, bm: int, bk: int,
 # The kernel: the dense semiring combine, gated on the occupancy product.
 # -----------------------------------------------------------------------------
 def _sparse_semiring_kernel(ao_ref, bo_ref, a_ref, b_ref, o_ref, *,
-                            semiring: str, sat: float, bk: int):
-    @pl.when(pl.program_id(2) == 0)
+                            semiring: str, sat: float, bk: int, nk: int,
+                            nn: int):
+    i, j, kk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kk == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref, _ZERO[semiring])
 
-    occupied = (ao_ref[0, 0] != 0) & (bo_ref[0, 0] != 0)
+    # Occupancy bitmaps are scalar-prefetched into SMEM, flattened
+    # row-major: a (1, 1) VMEM block per tile would violate the (8, 128)
+    # block tiling.
+    occupied = (ao_ref[i * nk + kk] != 0) & (bo_ref[kk * nn + j] != 0)
 
-    if semiring in ("count", "bool"):
-        ceil = 1.0 if semiring == "bool" else sat
-
-        @pl.when(occupied)
-        def _combine():
-            prod = jax.lax.dot_general(
-                a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            o_ref[...] = jnp.minimum(o_ref[...] + prod, ceil)
-    else:  # minplus: VPU broadcast-min over the K tile
-
-        @pl.when(occupied)
-        def _combine():
-            a = a_ref[...]
-            b = b_ref[...]
-
-            def body(k, acc):
-                return jnp.minimum(acc, a[:, k][:, None] + b[k, :][None, :])
-
-            o_ref[...] = jax.lax.fori_loop(0, bk, body, o_ref[...])
+    @pl.when(occupied)
+    def _combine():
+        o_ref[...] = combine_tile(o_ref[...], a_ref[...], b_ref[...],
+                                  semiring=semiring, sat=sat, bk=bk)
 
 
 @functools.partial(jax.jit,
@@ -102,20 +91,23 @@ def _pallas_sparse_matmul(a, b, *, semiring: str, bm: int, bn: int, bk: int,
     zero = jnp.float32(_ZERO[semiring])
     a_p = jnp.full((mp, kp), zero).at[:m, :k].set(a.astype(jnp.float32))
     b_p = jnp.full((kp, np_), zero).at[:k, :n].set(b.astype(jnp.float32))
-    a_occ = tile_occupancy(a_p, bm, bk, semiring)
-    b_occ = tile_occupancy(b_p, bk, bn, semiring)
+    nm, nn, nk = mp // bm, np_ // bn, kp // bk
+    a_occ = tile_occupancy(a_p, bm, bk, semiring).reshape(-1)
+    b_occ = tile_occupancy(b_p, bk, bn, semiring).reshape(-1)
 
     out = pl.pallas_call(
         functools.partial(_sparse_semiring_kernel, semiring=semiring,
-                          sat=sat, bk=bk),
-        grid=(mp // bm, np_ // bn, kp // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+                          sat=sat, bk=bk, nk=nk, nn=nn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nm, nn, nk),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, kk, ao, bo: (i, kk)),
+                pl.BlockSpec((bk, bn), lambda i, j, kk, ao, bo: (kk, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn),
+                                   lambda i, j, kk, ao, bo: (i, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
     )(a_occ, b_occ, a_p, b_p)
@@ -145,7 +137,8 @@ def sparse_semiring_matmul(a: jnp.ndarray, b: jnp.ndarray,
     if backend == "ref":
         return ref.sparse_semiring_matmul_ref(a, b, semiring, sat=sat)
     fn = functools.partial(_pallas_sparse_matmul, semiring=semiring, bm=bm,
-                           bn=bn, bk=bk, sat=sat, interpret=_interp(interpret))
+                           bn=bn, bk=bk, sat=sat,
+                           interpret=interpret_default(interpret))
     if a.ndim == 3 or b.ndim == 3:
         if a.ndim == 2:
             a = jnp.broadcast_to(a[None], (b.shape[0],) + a.shape)

@@ -51,6 +51,12 @@ from . import interpret_default, kernel_backend, ref
 
 __all__ = ["waterfill_step"]
 
+# Scoped VMEM the kernel may use.  The (fp, 1) f32 per-flow scratch pads
+# every row to 128 lanes, so the ECN lane's third scratch alone overflows
+# Mosaic's 16 MiB default at sf(q=19) permutation (F=10,830); a v5e core
+# has 128 MiB of VMEM.
+_VMEM_LIMIT_BYTES = 32 * 2**20
+
 
 def _waterfill_kernel(edges_ref, w_ref, desired_ref, active_ref, cap_ref,
                       *refs, e_tot: int, be: int, n_e_tiles: int, bf: int,
@@ -205,6 +211,8 @@ def _pallas_waterfill(edges, w, desired, active, cap, *, e_tot: int,
                         pltpu.VMEM((fp, 1), jnp.float32),
                         pltpu.VMEM((fp, 1), jnp.float32)]
         + ([pltpu.VMEM((fp, 1), jnp.float32)] if want_util else []),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(edges_p, w_p, d_p, act_p, cap_p)
     return tuple(o[:f, 0] for o in out)

@@ -7,6 +7,16 @@ import numpy as np
 import pytest
 
 
+@pytest.fixture(scope="session", autouse=True)
+def _no_persistent_compile_cache():
+    """Keep jax's persistent compilation cache off for the whole session,
+    even after a CLI entry point has placed it: a test must not read
+    programs an earlier run compiled (the ``--cell-timeout-s`` tests
+    rely on a cold cell being slower than their timeout)."""
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
+
+
 @pytest.fixture(scope="session")
 def sf5():
     from repro.core.topology import slim_fly

@@ -200,15 +200,18 @@ def test_churn_state_multi_event_and_zero_conv():
         assert bool(d[0]) == dead and bool(u[0]) == dead, i
 
 
-# ---- churn off reproduces the PR 9 golden cells bit-for-bit -----------------
+# ---- churn off reproduces the golden cells bit-for-bit ----------------------
 @pytest.mark.parametrize("routing,evaluator", sorted(GOLDEN))
 def test_churn_rate_zero_reproduces_golden_bitwise(session, routing,
                                                    evaluator):
     """`churn(rate=0)` realizes an empty schedule and must return the
-    inner bundle itself — metrics equal the golden cells with ==, per
-    transport mode, and no recovery-lane keys appear."""
+    inner bundle itself — metrics equal the unwrapped cell's and the
+    golden cells with ==, per transport mode, and no recovery-lane keys
+    appear."""
     rr = session.run("clique(k=6)", f"churn(of={routing},rate=0)",
                      "uniform", evaluator, seed=0)
+    base = session.run("clique(k=6)", routing, "uniform", evaluator, seed=0)
+    assert rr.metrics == base.metrics
     want = GOLDEN[(routing, evaluator)]
     assert set(rr.metrics) == set(want)
     for k, v in want.items():
@@ -238,32 +241,41 @@ def test_churn_cell_runs_and_reports_meta(session):
 
 
 # ---- availability-SLO acceptance --------------------------------------------
-_CHURN = ("churn(of={},rate=0.4,mtbf=100,mttr=80,conv=8)")
+_CHURN = ("churn(of={},rate=0.4,mtbf=100,mttr=160,conv=8)")
 _HALFPERM = "permutation(flow_size=1000000000.0,frac=0.5)"
-_AVAIL = "availability(steps=400,slo=0.8)"
+_AVAIL = "availability(steps=400,slo=0.8,seeds=4)"
+_DRAWS = range(8)
 
 
 def test_availability_fatpaths_beats_pinned_ecmp(session):
     """The PR's headline: under a flapping fabric at half-load, FatPaths
     with the recovery lanes armed re-routes around each outage and
-    sustains strictly higher availability(slo=0.8) than the layer-pinned
-    ecmp control, whose flows stay dark for every outage + nothing else
-    runs in their place."""
-    fp = session.run("clique(k=6)", _CHURN.format("fatpaths(n_layers=9)"),
-                     _HALFPERM, _AVAIL, seed=0)
-    ec = session.run("clique(k=6)", _CHURN.format("ecmp(n=4)"),
-                     _HALFPERM, _AVAIL, seed=0)
-    assert 0 < fp.metrics["availability"] < 1
-    assert fp.metrics["availability"] > ec.metrics["availability"]
-    for rr in (fp, ec):
-        assert rr.metrics["plateau_goodput"] > 0
-        assert rr.metrics["violations"] >= 1
-        assert rr.metrics["max_outage_steps"] > 0
-        assert rr.meta["availability_slo"] == 0.8
-        assert (len(rr.meta["curve_steps"]) == len(rr.meta["goodput_curve"])
-                == len(rr.meta["pristine_curve"]))
-    assert fp.meta["pristine_routing"] == "fatpaths(n_layers=9)"
-    assert ec.meta["pristine_routing"] == "ecmp(n=4)"
+    sustains higher availability(slo=0.8) than the layer-pinned ecmp
+    control, whose flows stay dark for every outage + nothing else runs
+    in their place.  Availability is averaged over eight churn draws
+    (four sim seeds each): on a 6-clique most flows have no layer that
+    avoids a given link, so one draw's outcome is mostly the draw."""
+    def runs(scheme):
+        return [session.run("clique(k=6)", _CHURN.format(scheme),
+                            _HALFPERM, _AVAIL, seed=s) for s in _DRAWS]
+
+    fp, ec = runs("fatpaths(n_layers=9)"), runs("ecmp(n=4)")
+    avail = {name: np.mean([rr.metrics["availability"] for rr in rs])
+             for name, rs in (("fp", fp), ("ec", ec))}
+    assert 0 < avail["fp"] < 1
+    assert avail["fp"] > avail["ec"]
+    for rs in (fp, ec):
+        assert sum(rr.metrics["violations"] for rr in rs) >= 1
+        assert max(rr.metrics["max_outage_steps"] for rr in rs) > 0
+        for rr in rs:
+            assert rr.metrics["plateau_goodput"] > 0
+            assert rr.meta["availability_slo"] == 0.8
+            assert (len(rr.meta["curve_steps"])
+                    == len(rr.meta["goodput_curve"])
+                    == len(rr.meta["pristine_curve"]))
+    assert all(rr.meta["pristine_routing"] == "fatpaths(n_layers=9)"
+               for rr in fp)
+    assert all(rr.meta["pristine_routing"] == "ecmp(n=4)" for rr in ec)
 
 
 def test_availability_without_churn_is_trivial(session):

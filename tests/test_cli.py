@@ -1,11 +1,14 @@
 """Experiments CLI: error paths (unknown names, out-of-whitelist
-parameters), ``list`` output, single-cell ``run``, artifact ``diff``."""
+parameters), ``list`` output, single-cell ``run``, artifact ``diff``,
+and where ``--devices N`` finds its devices."""
 
 import json
+import os
+import sys
 
 import pytest
 
-from repro.experiments.__main__ import main
+from repro.experiments.__main__ import _ensure_devices, main
 
 QUICK = ["--evaluator", "transport(steps=30)"]
 
@@ -129,3 +132,23 @@ def test_diff_identical_and_differing(artifact, capsys, tmp_path):
     assert main(["diff", artifact, other]) == 1
     cap = capsys.readouterr()
     assert "fct_p50_us" in cap.out and "difference" in cap.err
+
+
+# ---- --devices N: forced host devices only where the caller chose the CPU --
+@pytest.mark.parametrize("platforms,forced", [("cpu", True), (None, False),
+                                              ("tpu", False)])
+def test_ensure_devices_forces_host_devices_only_on_cpu(monkeypatch,
+                                                        platforms, forced):
+    """Before jax loads, ``--devices 4`` forces four host CPU devices
+    only under ``JAX_PLATFORMS=cpu``; unset (jax picks the host's
+    accelerator) or an accelerator platform leaves the chips alone."""
+    monkeypatch.delitem(sys.modules, "jax", raising=False)
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    _ensure_devices(4)
+    want = "--xla_force_host_platform_device_count=4" if forced else None
+    assert os.environ.get("XLA_FLAGS") == want
+    assert os.environ.get("JAX_PLATFORMS") == platforms
