@@ -8,13 +8,16 @@ calls (``repro.experiments.Session`` and ``dist_sweep``).  Phases:
 
 1. device   — a TPU must be present (else exit nonzero, naming what jax
    found instead); the kernels resolve to compiled Pallas.
-2. cells    — sf(q=19) (722 routers, 10,830 endpoints) x permutation x
-   transport(steps=2000) under fatpaths(n_layers=9,rho=0.6) and ecmp.
-   Each cell runs twice in one Session: the first call pays the stack
-   build and the compiles (set-up), the second is the run alone.  The
-   fatpaths cell is run again, the same way, on the ``ref`` (jnp)
-   water-filling backend and must agree with the Pallas run within the
-   kernel-vs-oracle tolerances of tests/test_waterfill.py.
+2. cells    — sf(q=19) (722 routers, 10,830 endpoints) x permutation of
+   4 MiB flows x transport(steps=2000) under fatpaths(n_layers=9,rho=0.6)
+   and ecmp.  The flows outlast one 64-step scan chunk, so the chunked
+   scan carries its state across chunks (checked: more than one chunk
+   ran), and the two schemes must give different metrics.  Each cell
+   runs twice in one Session: the first call pays the stack build and
+   the compiles (set-up), the second is the run alone.  The fatpaths
+   cell is run again, the same way, under REPRO_KERNEL_BACKEND=ref (the
+   jnp water-filling oracle) and must agree with the Pallas run within
+   the kernel-vs-oracle tolerances of tests/test_waterfill.py.
 3. minplus  — a fatpaths(scheme=ksp) stack on sf(q=5), whose (min, +)
    distances run the semiring kernel: its tables must be loop-free, and
    the dense and block-sparse kernels must equal the jnp oracle bitwise.
@@ -32,6 +35,7 @@ Any failure exits nonzero.  The last line of stdout is one JSON object:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import math
 import os
@@ -42,6 +46,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 REAL_TOPO = "sf(q=19)"
 REAL_SCHEMES = ("fatpaths(n_layers=9,rho=0.6)", "ecmp")
+REAL_PATTERN = "permutation(flow_size=4194304.0)"
 TRANSPORT = "transport(steps=2000)"
 GRID = dict(topos=["sf(q=5)", "sf(q=11)"], routings=["ecmp", "fatpaths"],
             patterns=["permutation"], evaluators=[TRANSPORT])
@@ -113,24 +118,55 @@ def timed_cell(session, spec):
         f"(stack build {first.meta['build_s']:.3f}) "
         f"run_s={t2 - t1:.3f} chunks={rr.meta['sweep_chunks']} "
         f"flows={rr.meta['n_flows']} "
+        f"fct_mean_us={rr.metrics['fct_mean_us']!r} "
+        f"fct_p50_us={rr.metrics['fct_p50_us']!r} "
         f"fct_p99_us={rr.metrics['fct_p99_us']!r} "
         f"delivered_bytes={rr.meta['delivered_bytes']!r} "
         f"finished={rr.metrics['finished']!r}")
     return rr
 
 
-def phase_cells(session, topo: str = REAL_TOPO,
-                schemes=REAL_SCHEMES, evaluator: str = TRANSPORT) -> None:
+@contextlib.contextmanager
+def kernel_backend_env(name: str):
+    """Run the enclosed cells with REPRO_KERNEL_BACKEND=name."""
+    from repro.kernels import kernel_backend
+
+    was = os.environ.get("REPRO_KERNEL_BACKEND")
+    os.environ["REPRO_KERNEL_BACKEND"] = name
+    try:
+        check(kernel_backend() == name,
+              f"REPRO_KERNEL_BACKEND={name} resolves to {kernel_backend()}")
+        yield
+    finally:
+        if was is None:
+            del os.environ["REPRO_KERNEL_BACKEND"]
+        else:
+            os.environ["REPRO_KERNEL_BACKEND"] = was
+
+
+def phase_cells(session, topo: str = REAL_TOPO, schemes=REAL_SCHEMES,
+                pattern: str = REAL_PATTERN,
+                evaluator: str = TRANSPORT) -> None:
     from repro.experiments import ExperimentSpec
 
-    def cell(scheme, ev):
+    def cell(scheme):
         return timed_cell(session, ExperimentSpec.make(
-            topo, scheme, "permutation", ev))
+            topo, scheme, pattern, evaluator))
 
-    runs = {scheme: cell(scheme, evaluator) for scheme in schemes}
+    runs = {scheme: cell(scheme) for scheme in schemes}
+    for scheme, rr in runs.items():
+        check(rr.meta["sweep_chunks"] > 1,
+              f"{topo} {scheme}: the scan ran {rr.meta['sweep_chunks']} "
+              f"chunk(s); the traffic must outlast one")
+    a, b = (runs[s].metrics for s in schemes[:2])
+    check(a != b, f"{topo}: {schemes[0]} and {schemes[1]} give identical "
+          f"metrics {a}")
     scheme = schemes[0]
     pal = runs[scheme]
-    ref = cell(scheme, evaluator[:-1] + ",backend=ref)")
+    # The kernel backend is part of the scan's jit-static config, so the
+    # same cell under the override compiles and runs the jnp oracle.
+    with kernel_backend_env("ref"):
+        ref = cell(scheme)
     for k, rtol in REF_RTOL.items():
         a, b = pal.metrics[k], ref.metrics[k]
         check(abs(a - b) <= rtol * max(abs(a), abs(b)),

