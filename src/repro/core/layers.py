@@ -42,13 +42,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from . import paths as paths_mod
 from .topology import Topology
 
@@ -314,8 +314,10 @@ def build_layers(topo: Topology, n_layers: int, rho: float,
 
     All L layers' tables come from ONE batched device program; there is
     no per-layer host loop for table construction.  ``build_stats`` on
-    the result records the host (adjacency sampling) vs device (semiring
-    table construction) wall-time split.
+    the result splits the build's wall time (``total_s``) into the device
+    table program, dispatch to ``block_until_ready`` (``device_s``, the
+    ``stack.device`` span), and the host rest (``host_s``: adjacency
+    sampling, table conversion, compression in ``stack.compress``).
 
     ``engine`` overrides the ``REPRO_PATH_ENGINE`` resolution (``dense``
     below 512 routers, ``blocked`` — frontier APSP + chunked forwarding
@@ -341,52 +343,54 @@ def build_layers(topo: Topology, n_layers: int, rho: float,
     nbr = jnp.asarray(paths_mod.neighbor_table(adj))
     adj_j = jnp.asarray(adj)
 
-    t0 = time.perf_counter()
-    if scheme == "pi_min":
-        iu, ju = np.nonzero(np.triu(adj, 1))
-        t_dev = time.perf_counter()
-        la, nh, reach, dist = _pi_min_program(
-            adj_j, nbr, jnp.asarray(iu), jnp.asarray(ju), key, n_layers,
-            float(rho), max_len, eng)
-    elif scheme == "ksp":
-        t_dev = time.perf_counter()
-        la, nh, reach, dist = _ksp_program(adj_j, nbr, key, n_layers,
-                                           max_len, eng)
-    else:
-        layer_adjs: List[np.ndarray] = [adj.copy()]
-        if scheme in ("rand", "undir"):
-            for _ in range(n_layers - 1):
-                layer_adjs.append(
-                    _rand_layer(adj, rho, rng, oriented=(scheme == "rand")))
-        elif scheme == "spain":
-            for _ in range(n_layers - 1):
-                root = int(rng.integers(n))
-                layer_adjs.append(_bfs_tree(adj, root, rng))
-        elif scheme == "past":
-            for _ in range(n_layers - 1):
-                layer_adjs.append(adj.copy())  # re-randomised tie-breaks
+    with tracing.record() as rec:
+        if scheme == "pi_min":
+            iu, ju = np.nonzero(np.triu(adj, 1))
+            program = functools.partial(
+                _pi_min_program, adj_j, nbr, jnp.asarray(iu), jnp.asarray(ju),
+                key, n_layers, float(rho), max_len, eng)
+        elif scheme == "ksp":
+            program = functools.partial(_ksp_program, adj_j, nbr, key,
+                                        n_layers, max_len, eng)
         else:
-            raise ValueError(f"unknown scheme {scheme!r}")
-        la = jnp.asarray(np.stack(layer_adjs))
-        t_dev = time.perf_counter()
-        nh, reach, dist = paths_mod._layer_tables_program(la, nbr, key,
-                                                          max_len, eng)
-    jax.block_until_ready(nh)
-    t1 = time.perf_counter()
+            layer_adjs: List[np.ndarray] = [adj.copy()]
+            if scheme in ("rand", "undir"):
+                for _ in range(n_layers - 1):
+                    layer_adjs.append(_rand_layer(
+                        adj, rho, rng, oriented=(scheme == "rand")))
+            elif scheme == "spain":
+                for _ in range(n_layers - 1):
+                    root = int(rng.integers(n))
+                    layer_adjs.append(_bfs_tree(adj, root, rng))
+            elif scheme == "past":
+                for _ in range(n_layers - 1):
+                    layer_adjs.append(adj.copy())  # re-randomised tie-breaks
+            else:
+                raise ValueError(f"unknown scheme {scheme!r}")
+            la = jnp.asarray(np.stack(layer_adjs))
 
-    reach_np = np.asarray(reach)
-    pathlen = np.where(reach_np, np.asarray(dist), _UNREACH).astype(np.int16)
-    nh_np = np.asarray(nh)
-    compressed = None
-    if rep == "compressed":
-        compressed = paths_mod.CompressedTables.from_dense(nh_np)
-    t2 = time.perf_counter()
+            def program():
+                return (la, *paths_mod._layer_tables_program(
+                    la, nbr, key, max_len, eng))
+        with tracing.span("stack.device"):
+            la, nh, reach, dist = program()
+            jax.block_until_ready(nh)
+
+        reach_np = np.asarray(reach)
+        pathlen = np.where(reach_np, np.asarray(dist),
+                           _UNREACH).astype(np.int16)
+        nh_np = np.asarray(nh)
+        compressed = None
+        if rep == "compressed":
+            with tracing.span("stack.compress"):
+                compressed = paths_mod.CompressedTables.from_dense(nh_np)
+    device_s = rec.seconds("stack.device")
     return LayeredRouting(
         topo=topo, scheme=scheme, rho=rho,
         nh=nh_np, reach=reach_np,
         pathlen=pathlen, layer_adj=np.asarray(la),
-        build_stats={"total_s": t2 - t0, "device_s": t1 - t_dev,
-                     "host_s": t_dev - t0, "compress_s": t2 - t1},
+        build_stats={"total_s": rec.wall_s, "device_s": device_s,
+                     "host_s": rec.wall_s - device_s},
         compressed=compressed,
     )
 

@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..kernels import kernel_backend
 from ..kernels.waterfill import waterfill_step
 from .layers import LayeredRouting
@@ -182,43 +183,44 @@ def ecmp_routing(topo: Topology, n_tables: int = 8, seed: int = 0,
     """Minimal-path-only multi-table routing: n differently tie-broken
     shortest-path tables (flow-hash ECMP / LetFlow substrate).  All n
     tables come out of one batched forwarding program (APSP is shared:
-    every table sees the same full-graph distances)."""
-    import time
-
+    every table sees the same full-graph distances).  ``build_stats``
+    splits the build's wall time as :func:`repro.core.layers.build_layers`
+    does."""
     from . import paths as paths_mod
 
     adj = np.asarray(topo.adj, dtype=bool)
     if max_len is None:
         max_len = max(6, topo.diameter_nominal + 2)
-    t0 = time.perf_counter()
-    engine = paths_mod.path_engine(adj.shape[0])
-    nbr = jnp.asarray(paths_mod.neighbor_table(adj))
-    stack = jnp.asarray(np.broadcast_to(adj[None], (n_tables,) + adj.shape))
-    t_dev = time.perf_counter()
-    dist_j = paths_mod.apsp_batched(jnp.asarray(adj)[None],
-                                    max_l=max_len)[0]
-    nh = paths_mod._forwarding_program(
-        stack, jnp.broadcast_to(dist_j[None], stack.shape), nbr,
-        jax.random.PRNGKey(seed), engine)
-    nh = np.asarray(jax.block_until_ready(nh)).copy()
-    t1 = time.perf_counter()
-    dist = np.asarray(dist_j)
-    reach = dist <= max_len
-    nh[:, ~reach] = -1
-    idx = np.arange(adj.shape[0])
-    nh[:, idx, idx] = idx
-    plen = np.where(reach, dist, 10_000).astype(np.int16)
-    compressed = None
-    if paths_mod.representation_for(adj.shape[0]) == "compressed":
-        compressed = paths_mod.CompressedTables.from_dense(nh)
-    t2 = time.perf_counter()
+    with tracing.record() as rec:
+        engine = paths_mod.path_engine(adj.shape[0])
+        nbr = jnp.asarray(paths_mod.neighbor_table(adj))
+        stack = jnp.asarray(np.broadcast_to(adj[None],
+                                            (n_tables,) + adj.shape))
+        with tracing.span("stack.device"):
+            dist_j = paths_mod.apsp_batched(jnp.asarray(adj)[None],
+                                            max_l=max_len)[0]
+            nh = paths_mod._forwarding_program(
+                stack, jnp.broadcast_to(dist_j[None], stack.shape), nbr,
+                jax.random.PRNGKey(seed), engine)
+            nh = np.asarray(jax.block_until_ready(nh)).copy()
+        dist = np.asarray(dist_j)
+        reach = dist <= max_len
+        nh[:, ~reach] = -1
+        idx = np.arange(adj.shape[0])
+        nh[:, idx, idx] = idx
+        plen = np.where(reach, dist, 10_000).astype(np.int16)
+        compressed = None
+        if paths_mod.representation_for(adj.shape[0]) == "compressed":
+            with tracing.span("stack.compress"):
+                compressed = paths_mod.CompressedTables.from_dense(nh)
+    device_s = rec.seconds("stack.device")
     return LayeredRouting(
         topo=topo, scheme="ecmp", rho=1.0,
         nh=nh, reach=np.stack([reach] * n_tables),
         pathlen=np.stack([plen] * n_tables),
         layer_adj=np.stack([adj] * n_tables),
-        build_stats={"total_s": t2 - t0, "device_s": t1 - t_dev,
-                     "host_s": (t_dev - t0) + (t2 - t1)},
+        build_stats={"total_s": rec.wall_s, "device_s": device_s,
+                     "host_s": rec.wall_s - device_s},
         compressed=compressed,
     )
 
@@ -298,6 +300,7 @@ def shape_signature(topo: Topology, routing: LayeredRouting,
     return (len(wl.src), n_edges + 2 * n_ep + 1, int(routing.nh.shape[0]))
 
 
+@tracing.span("prepare")
 def _prepare(topo: Topology, routing: LayeredRouting, wl: FlowWorkload,
              cfg: SimConfig):
     """Static arrays for the scan — including the per-layer path-edge
@@ -311,18 +314,20 @@ def _prepare(topo: Topology, routing: LayeredRouting, wl: FlowWorkload,
     src_r = jnp.asarray(wl.src_router)
     dst_r = jnp.asarray(wl.dst_router)
     ct = getattr(routing, "compressed", None)
-    if ct is not None:
-        edges, routed = _path_edge_tensor_compressed(
-            jnp.asarray(ct.nh_sets), jnp.asarray(ct.sel), jnp.asarray(eix),
-            src_r, dst_r, cfg.max_hops, ct.block)
-    else:
-        edges, routed = _path_edge_tensor(jnp.asarray(routing.nh),
-                                          jnp.asarray(eix), src_r, dst_r,
-                                          cfg.max_hops)
-    # Trim the hop axis to the longest realised path: the per-step cost
-    # then tracks actual path lengths, not the cfg.max_hops cap (padding
-    # is all -1 beyond the longest path by construction).
-    hmax = max(1, int((edges >= 0).sum(axis=2).max())) if edges.size else 1
+    with tracing.span("prepare.device"):
+        if ct is not None:
+            edges, routed = _path_edge_tensor_compressed(
+                jnp.asarray(ct.nh_sets), jnp.asarray(ct.sel),
+                jnp.asarray(eix), src_r, dst_r, cfg.max_hops, ct.block)
+        else:
+            edges, routed = _path_edge_tensor(jnp.asarray(routing.nh),
+                                              jnp.asarray(eix), src_r, dst_r,
+                                              cfg.max_hops)
+        # Trim the hop axis to the longest realised path: the per-step
+        # cost then tracks actual path lengths, not the cfg.max_hops cap
+        # (padding is all -1 beyond the longest path by construction).
+        hmax = (max(1, int((edges >= 0).sum(axis=2).max())) if edges.size
+                else 1)
     edges = edges[:, :, :hmax]
     n_flows = len(wl.src)
     src_e = jnp.asarray(wl.src + e_inj)
@@ -616,10 +621,11 @@ def _run_scan_impl(arrs, key0, cfg: SimConfig, static: Tuple[int, int, int]):
             churn_dead, link_unpick = _churn_state(
                 i, arrs["link_churn"], arrs["churn_pick_at"])
             cap_t = jnp.where(churn_dead, 0.0, cap_t)
-        wf = waterfill_step(edges, w, desired, cap_t, active=send,
-                            fair_iters=cfg.fair_iters,
-                            backend=cfg.kernel_backend or None,
-                            want_util=want_util)
+        with jax.named_scope("waterfill"):
+            wf = waterfill_step(edges, w, desired, cap_t, active=send,
+                                fair_iters=cfg.fair_iters,
+                                backend=cfg.kernel_backend or None,
+                                want_util=want_util)
         if want_util:
             sent, share, util = wf
         else:
@@ -878,6 +884,7 @@ def _run_scan_batch(arrs, keys, cfg: SimConfig,
     return jax.vmap(lambda k: _run_scan_impl(arrs, k, cfg, static))(keys)
 
 
+@tracing.span("finalise")
 def _to_result(size: np.ndarray, final, cfg: SimConfig,
                start: Optional[np.ndarray] = None) -> SimResult:
     remaining = np.asarray(final["remaining"])
@@ -1037,8 +1044,9 @@ def simulate(topo: Topology, routing: LayeredRouting, wl: FlowWorkload,
              cfg: SimConfig) -> SimResult:
     """Run the flow simulator; returns per-flow FCTs and aggregates."""
     jarrs, static = prepare(topo, routing, wl, cfg)
-    final = _run_scan(jarrs, jax.random.PRNGKey(cfg.seed),
-                      static_config(cfg), static)
+    with tracing.span("scan"):
+        final = jax.block_until_ready(_run_scan(
+            jarrs, jax.random.PRNGKey(cfg.seed), static_config(cfg), static))
     return _to_result(np.asarray(jarrs["size"]), final, cfg,
                       start=np.asarray(jarrs["start"]))
 
@@ -1054,7 +1062,9 @@ def simulate_seeds(topo: Topology, routing: LayeredRouting, wl: FlowWorkload,
         return []
     jarrs, static = prepare(topo, routing, wl, cfg)
     keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    finals = _run_scan_batch(jarrs, keys, static_config(cfg), static)
+    with tracing.span("scan"):
+        finals = jax.block_until_ready(
+            _run_scan_batch(jarrs, keys, static_config(cfg), static))
     size = np.asarray(jarrs["size"])
     start = np.asarray(jarrs["start"])
     return [

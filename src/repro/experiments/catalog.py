@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
 
+from .. import tracing
 from ..core import arrivals
 from ..core import routing as routing_mod
 from ..core import topology as topo_mod
@@ -512,6 +513,7 @@ def _anycast(topo, seed, replicas, policy, flow_size, window, process,
 # -----------------------------------------------------------------------------
 # Evaluators.  Signature: (session, cell, **kw) -> (metrics, meta).
 # -----------------------------------------------------------------------------
+@tracing.span("finalise")
 def _fct_metrics(sims) -> Dict[str, float]:
     fct = np.concatenate([r.fct[r.finished] for r in sims])
     tput = np.concatenate([r.throughput_per_flow for r in sims])

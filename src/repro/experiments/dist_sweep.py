@@ -65,13 +65,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..ckpt.sweep import SweepCheckpoint
 from ..core import transport as transport_mod
 from ..dist.sharding import P, Runtime, host_device_runtime
@@ -99,9 +99,7 @@ class _Work:
     e_tot: int
     n_layers: int
     ev_meta: Dict[str, Any]
-    pre: Dict[str, float]
-    post: Dict[str, float]
-    resolve_s: float
+    rec: tracing.Record          # the cell's resolve and plan (span "cell")
     size: Any = None             # (F,) float32, filled at dispatch
     start: Any = None            # (F,) float32 flow start times, ditto
 
@@ -231,71 +229,75 @@ def _dispatch_bucket(works: List[_Work], rt: Runtime, bucket_index: int):
         w.size = np.asarray(arrs["size"])
         w.start = np.asarray(arrs["start"])
         prepared.append((arrs, static))
-    n_flows = max(w.n_flows for w in works)
-    n_edges = max(w.e_tot for w in works)
-    hop_slots = max(a["path_edges"].shape[2] for a, _ in prepared)
-    static_pad = None
-    padded_cells = []
-    for arrs, static in prepared:
-        padded, static_pad = transport_mod.pad_prepared(
-            arrs, static, n_flows=n_flows, n_edges=n_edges,
-            hop_slots=hop_slots)
-        padded_cells.append(padded)
-    del prepared
+    with tracing.span("sweep.pad"):
+        n_flows = max(w.n_flows for w in works)
+        n_edges = max(w.e_tot for w in works)
+        hop_slots = max(a["path_edges"].shape[2] for a, _ in prepared)
+        static_pad = None
+        padded_cells = []
+        for arrs, static in prepared:
+            padded, static_pad = transport_mod.pad_prepared(
+                arrs, static, n_flows=n_flows, n_edges=n_edges,
+                hop_slots=hop_slots)
+            padded_cells.append(padded)
+        del prepared
 
-    n_dev = 1 if rt.mesh is None else rt.fsdp_size
-    seed_counts = {len(w.sim_seeds) for w in works}
-    # Nest only when the OUTER (cell) axis can still feed the mesh:
-    # sharding happens over whatever axis the program batches, so a
-    # 6-cell x 8-seed bucket on an 8-device mesh must use the flat
-    # 48-element layout (duplicated operands, full parallelism), not 6
-    # nested units serialized onto one device.
-    nest_seeds = (seed_counts == {max(seed_counts)}
-                  and max(seed_counts) > 1
-                  and (n_dev == 1 or len(works) >= n_dev))
-    if nest_seeds:
-        # units = cells; keys (C, S, 2); operands one copy per cell.
-        units = list(padded_cells)
-        key_rows = [[jax.random.PRNGKey(s) for s in w.sim_seeds]
-                    for w in works]
-        scan, sharded = _vmapped_scan_seeds, _sharded_scan_seeds
-    else:
-        # units = (cell, seed) elements; operands duplicated per seed.
-        units, key_rows = [], []
-        for w, padded in zip(works, padded_cells):
-            for s in w.sim_seeds:
-                units.append(padded)
-                key_rows.append(jax.random.PRNGKey(s))
-        scan, sharded = _vmapped_scan, _sharded_scan
-    elements = [(wi, s) for wi, w in enumerate(works) for s in w.sim_seeds]
+        n_dev = 1 if rt.mesh is None else rt.fsdp_size
+        seed_counts = {len(w.sim_seeds) for w in works}
+        # Nest only when the OUTER (cell) axis can still feed the mesh:
+        # sharding happens over whatever axis the program batches, so a
+        # 6-cell x 8-seed bucket on an 8-device mesh must use the flat
+        # 48-element layout (duplicated operands, full parallelism), not 6
+        # nested units serialized onto one device.
+        nest_seeds = (seed_counts == {max(seed_counts)}
+                      and max(seed_counts) > 1
+                      and (n_dev == 1 or len(works) >= n_dev))
+        if nest_seeds:
+            # units = cells; keys (C, S, 2); operands one copy per cell.
+            units = list(padded_cells)
+            key_rows = [[jax.random.PRNGKey(s) for s in w.sim_seeds]
+                        for w in works]
+            scan, sharded = _vmapped_scan_seeds, _sharded_scan_seeds
+        else:
+            # units = (cell, seed) elements; operands duplicated per seed.
+            units, key_rows = [], []
+            for w, padded in zip(works, padded_cells):
+                for s in w.sim_seeds:
+                    units.append(padded)
+                    key_rows.append(jax.random.PRNGKey(s))
+            scan, sharded = _vmapped_scan, _sharded_scan
+        elements = [(wi, s) for wi, w in enumerate(works)
+                    for s in w.sim_seeds]
 
-    n_real = len(units)
-    use_shard_map = rt.mesh is not None and n_real >= n_dev
-    if use_shard_map:
-        while len(units) % n_dev:       # pad the unit axis to the mesh size
-            units.append(units[0])
-            key_rows.append(key_rows[0])
+        n_real = len(units)
+        use_shard_map = rt.mesh is not None and n_real >= n_dev
+        if use_shard_map:
+            while len(units) % n_dev:   # pad the unit axis to the mesh size
+                units.append(units[0])
+                key_rows.append(key_rows[0])
 
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *units)
-    keys = jnp.asarray(np.stack([np.asarray(k) for k in key_rows]))
+        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *units)
+        keys = jnp.asarray(np.stack([np.asarray(k) for k in key_rows]))
 
-    if rt.mesh is None:
-        finals = scan(stacked, keys, cfg0, static_pad)
-        mode = "vmap"
-    elif use_shard_map:
-        finals = sharded(rt, cfg0, static_pad)(stacked, keys)
-        mode = f"shard_map[{n_dev}]"
-    else:
-        dev = rt.mesh.devices.flat[bucket_index % n_dev]
-        stacked = jax.device_put(stacked, dev)
-        keys = jax.device_put(keys, dev)
-        finals = scan(stacked, keys, cfg0, static_pad)
-        mode = f"device[{bucket_index % n_dev}]"
+    with tracing.span("sweep.dispatch"):
+        if rt.mesh is None:
+            finals = scan(stacked, keys, cfg0, static_pad)
+            mode = "vmap"
+        elif use_shard_map:
+            finals = sharded(rt, cfg0, static_pad)(stacked, keys)
+            mode = f"shard_map[{n_dev}]"
+        else:
+            dev = rt.mesh.devices.flat[bucket_index % n_dev]
+            stacked = jax.device_put(stacked, dev)
+            keys = jax.device_put(keys, dev)
+            finals = scan(stacked, keys, cfg0, static_pad)
+            mode = f"device[{bucket_index % n_dev}]"
     mode += "+seednest" if nest_seeds else ""
     return finals, (elements, nest_seeds), mode, (n_flows, n_edges,
                                                   hop_slots)
 
 
+@tracing.span("sweep.collect")
 def _finalize_bucket(works: List[_Work], finals, elements
                      ) -> Dict[int, list]:
     """Block on one bucket's device results and split them back into
@@ -369,18 +371,18 @@ def dist_sweep(session: Session, cells: List[ExperimentSpec], *,
             # mat / fabric / custom evaluators: sequential fallback.
             results.append(emit(session.run(spec)))
             continue
-        t0 = time.perf_counter()
-        pre = session.stats_snapshot()
-        cell = session.resolve(spec)
-        cfg, sim_seeds = transport_plan(cell, **kw)
-        n_flows, e_tot, n_layers = transport_mod.shape_signature(
-            cell.topo, cell.bundle.routing, cell.workload)
+        # The cell's own record holds its resolve and plan; its share of
+        # the simulation is in its bucket's record.
+        with tracing.record(spec.cell_id) as rec, tracing.span("cell"):
+            cell = session.resolve(spec)
+            cfg, sim_seeds = transport_plan(cell, **kw)
+            n_flows, e_tot, n_layers = transport_mod.shape_signature(
+                cell.topo, cell.bundle.routing, cell.workload)
+            ev_meta = transport_meta(cell, cfg, sim_seeds)
         batched.append(_Work(
             spec=spec, cell=cell, cfg=cfg, sim_seeds=sim_seeds,
             n_flows=n_flows, e_tot=e_tot, n_layers=n_layers,
-            ev_meta=transport_meta(cell, cfg, sim_seeds),
-            pre=pre, post=session.stats_snapshot(),
-            resolve_s=time.perf_counter() - t0))
+            ev_meta=ev_meta, rec=rec))
     if n_resumed:
         say(f"# resumed {n_resumed} completed cell(s) from checkpoint")
 
@@ -402,7 +404,6 @@ def dist_sweep(session: Session, cells: List[ExperimentSpec], *,
     # size): an unbounded launch-everything-first loop would hold every
     # bucket's stacked device operands live at once, scaling peak
     # memory with the whole grid instead of the window.
-    t_sim = time.perf_counter()
     n_dev = max(1, rt.fsdp_size)
     max_in_flight = max(4, 2 * n_dev)
     in_flight: List[tuple] = []
@@ -412,9 +413,9 @@ def dist_sweep(session: Session, cells: List[ExperimentSpec], *,
         # Structured quarantine record: empty metrics, the failure in
         # meta, never checkpointed (a resume re-attempts the cell).
         results.append(emit(session.finish_result(
-            w.spec, w.cell, {}, w.ev_meta, w.pre, w.resolve_s,
-            extra_meta={"sweep_bucket": bi, "error": error},
-            post=w.post), persist=False))
+            w.spec, w.cell, {}, w.ev_meta, w.rec, w.rec.seconds("cell"),
+            extra_meta={"sweep_bucket": bi, "error": error}),
+            persist=False))
 
     def quarantine(bi: int, works: List[_Work], e: Exception):
         say(f"# bucket {bi}: batched execution failed "
@@ -424,13 +425,15 @@ def dist_sweep(session: Session, cells: List[ExperimentSpec], *,
                                "exception": type(e).__name__,
                                "message": str(e)[:500]})
 
-    def finalize(bi, works, finals, desc, t_disp):
+    def finalize(bi, works, finals, desc, brec):
         try:
-            sims = _finalize_bucket(works, finals, desc)
+            with brec:
+                sims = _finalize_bucket(works, finals, desc)
         except Exception as e:                      # noqa: BLE001
             quarantine(bi, works, e)
             return
-        bucket_wall = time.perf_counter() - t_disp
+        # From the bucket's dispatch to the end of its collect.
+        bucket_wall = brec.wall_s
         for wi, w in enumerate(works):
             bad = [r for r in sims[wi]
                    if not (np.all(np.isfinite(r.delivered))
@@ -441,34 +444,37 @@ def dist_sweep(session: Session, cells: List[ExperimentSpec], *,
                 emit_error(bi, w, {"type": "nonfinite",
                                    "seeds_bad": len(bad)})
                 continue
-            metrics = fct_metrics(sims[wi])
-            wall = w.resolve_s + bucket_wall * (len(w.sim_seeds)
-                                                / max(1, len(desc[0])))
+            with w.rec:
+                metrics = fct_metrics(sims[wi])
+            wall = w.rec.seconds("cell") + bucket_wall * (
+                len(w.sim_seeds) / max(1, len(desc[0])))
             results.append(emit(session.finish_result(
-                w.spec, w.cell, metrics, w.ev_meta, w.pre, wall,
+                w.spec, w.cell, metrics, w.ev_meta, w.rec, wall,
                 extra_meta={"sweep_bucket": bi, **sim_meta(sims[wi])},
-                post=w.post)))
+                shared=(brec,))))
 
-    for bi, works in enumerate(buckets.values()):
-        t_disp = time.perf_counter()
-        n_buckets += 1
-        try:
-            finals, desc, mode, (nf, ne, nh) = _dispatch_bucket(works, rt,
-                                                                bi)
-        except Exception as e:                      # noqa: BLE001
-            quarantine(bi, works, e)
-            continue
-        say(f"# bucket {bi}: {len(works)} cells x seeds = {len(desc[0])} "
-            f"programs via {mode}, padded to F={nf} E={ne} H={nh}")
-        in_flight.append((bi, works, finals, desc, t_disp))
-        n_elems += len(desc[0])
-        while len(in_flight) > max_in_flight:
+    with tracing.record() as sim_rec:
+        for bi, works in enumerate(buckets.values()):
+            brec = tracing.record(f"sweep bucket {bi}")
+            n_buckets += 1
+            try:
+                with brec:
+                    finals, desc, mode, (nf, ne, nh) = _dispatch_bucket(
+                        works, rt, bi)
+            except Exception as e:                  # noqa: BLE001
+                quarantine(bi, works, e)
+                continue
+            say(f"# bucket {bi}: {len(works)} cells x seeds = "
+                f"{len(desc[0])} programs via {mode}, padded to F={nf} "
+                f"E={ne} H={nh}")
+            in_flight.append((bi, works, finals, desc, brec))
+            n_elems += len(desc[0])
+            while len(in_flight) > max_in_flight:
+                finalize(*in_flight.pop(0))
+        while in_flight:
             finalize(*in_flight.pop(0))
-    while in_flight:
-        finalize(*in_flight.pop(0))
     if n_buckets:
         say(f"# {n_buckets} bucket(s), {n_elems} batched programs, "
-            f"simulate wall {time.perf_counter() - t_sim:.2f}s "
-            f"on {n_dev} device(s)")
+            f"simulate wall {sim_rec.wall_s:.2f}s on {n_dev} device(s)")
 
     return order_results(results, [c.cell_id for c in cells])

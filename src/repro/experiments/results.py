@@ -67,13 +67,14 @@ def results_from_json(text: str) -> List[RunResult]:
 
 
 # Meta keys that describe HOW a cell was executed (timings, cache
-# hit/miss counters, batch bookkeeping), not WHAT it computed.  They
-# legitimately differ between a sequential sweep and a distributed one
-# (artifact builds land on different cells, walls differ), so the
-# cell-identity comparison below ignores them.
+# hit/miss counters, batch bookkeeping, the repro.tracing spans and
+# counters), not WHAT it computed.  They legitimately differ between a
+# sequential sweep and a distributed one (artifact builds land on
+# different cells, walls differ), so the cell-identity comparison below
+# ignores them.
 EXECUTION_META_KEYS = frozenset({
     "build_s", "build_device_s", "cache_builds", "cache_hits",
-    "sweep_bucket", "sweep_resumed", "sweep_chunks",
+    "sweep_bucket", "sweep_resumed", "sweep_chunks", "spans", "counts",
 })
 
 

@@ -6,20 +6,25 @@ workloads and :class:`~repro.dist.fabric.ClusterFabric` instances are
 built at most once, whatever order the cells run in.  ``ecmp`` and
 ``letflow`` cells share one minimal-table stack; a ``fabric`` evaluator
 cell reuses the very same layer stack its ``fatpaths`` transport sibling
-built.  ``session.stats`` counts builds vs hits AND accumulates build
-wall time — ``build_wall_s`` overall, ``<kind>_build_s`` per artifact
-kind, and the device/host split (``build_device_s``/``build_host_s``)
-reported by the batched semiring layer builders — so sweeps can expose
-their build-vs-simulate split (each ``RunResult.meta`` carries the
-per-cell ``build_s`` / ``cache_hits`` / ``cache_builds``).
+built.  ``session.stats`` counts builds vs hits per artifact kind
+(``<kind>_build`` / ``<kind>_hit``) and accumulates build wall time
+(``build_wall_s``) and the device share the layer builders report
+(``build_device_s``).
+
+Each build runs in a :mod:`repro.tracing` span named for its kind
+(``topology``, ``stack``, ``workload``, ``fabric``, ``fabric_cell``);
+:meth:`Session.run` records a cell's spans and counters, and each
+``RunResult.meta`` carries them (``spans``, ``counts``) with the
+per-cell ``build_s`` / ``build_device_s`` / ``cache_hits`` /
+``cache_builds`` derived from them.
 """
 
 from __future__ import annotations
 
 import collections
-import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
+from .. import tracing
 from ..core.layers import build_layers
 from ..core.topology import Topology
 from ..core.traffic import FlowWorkload
@@ -31,6 +36,11 @@ from .results import RunResult
 from .specs import ExperimentSpec, Spec, SpecLike
 
 __all__ = ["Session", "ResolvedCell"]
+
+# The span each memoized artifact kind is built in; ``build_s`` is their
+# sum over a cell.
+_BUILD_SPANS = {"topo": "topology", "stack": "stack", "workload": "workload",
+                "fabric": "fabric", "fabric_cell": "fabric_cell"}
 
 
 class ResolvedCell:
@@ -54,22 +64,20 @@ class Session:
 
     # ---- memoization core ----------------------------------------------------
     def _memo(self, key: tuple, build: Callable[[], Any]) -> Any:
+        kind = key[0]
         if key in self._cache:
-            self.stats[f"{key[0]}_hit"] += 1
+            self.stats[f"{kind}_hit"] += 1
+            tracing.count(f"{kind}_hit")
             return self._cache[key]
-        self.stats[f"{key[0]}_build"] += 1
-        t0 = time.perf_counter()
-        value = build()
-        dt = time.perf_counter() - t0
-        # Wall-time split: total per artifact kind, plus the device/host
-        # breakdown the batched layer builders report (Counter holds
-        # floats fine).
-        self.stats[f"{key[0]}_build_s"] += dt
-        self.stats["build_wall_s"] += dt
+        self.stats[f"{kind}_build"] += 1
+        tracing.count(f"{kind}_build")
+        with tracing.span(_BUILD_SPANS[kind]) as sp:
+            value = build()
+        # Counter holds floats fine.
+        self.stats["build_wall_s"] += sp.seconds
         bs = getattr(value, "build_stats", None)
         if isinstance(bs, dict):
             self.stats["build_device_s"] += bs.get("device_s", 0.0)
-            self.stats["build_host_s"] += bs.get("host_s", 0.0)
         self._cache[key] = value
         return value
 
@@ -156,6 +164,7 @@ class Session:
             flowlet_quanta=int(flowlet_quanta), layers=lr, ecmp=lr))
 
     # ---- cell execution ------------------------------------------------------
+    @tracing.span("resolve")
     def resolve(self, spec: ExperimentSpec) -> ResolvedCell:
         return ResolvedCell(
             spec=spec,
@@ -182,49 +191,37 @@ class Session:
                                   evaluator=Spec.coerce(evaluator),
                                   seed=int(seed))
         fn, kw = EVALUATORS.resolve(spec.evaluator)
-        t0 = time.perf_counter()
-        pre = self.stats_snapshot()
-        cell = self.resolve(spec)
-        metrics, meta = fn(self, cell, **kw)
-        wall = time.perf_counter() - t0
-        # One consistent snapshot AFTER the evaluator: builds an evaluator
+        # The record spans resolve AND the evaluator: builds an evaluator
         # triggers itself (e.g. a fabric cell building via the session)
         # count as build time for this cell, not as simulate time.
-        return self.finish_result(spec, cell, metrics, meta, pre, wall)
-
-    # Execution-bookkeeping counters snapshotted around each cell so the
-    # per-cell build-vs-simulate split can be attributed (dist_sweep uses
-    # the same pair of hooks around its resolve phase).
-    _SNAPSHOT_KEYS = ("build_wall_s", "build_device_s", "stack_build",
-                      "stack_hit")
-
-    def stats_snapshot(self) -> Dict[str, float]:
-        return {k: self.stats[k] for k in self._SNAPSHOT_KEYS}
+        with tracing.record(spec.cell_id) as rec, tracing.span("cell") as sp:
+            cell = self.resolve(spec)
+            metrics, meta = fn(self, cell, **kw)
+        return self.finish_result(spec, cell, metrics, meta, rec, sp.seconds)
 
     def finish_result(self, spec: ExperimentSpec, cell: ResolvedCell,
                       metrics: Dict[str, float], ev_meta: Dict[str, Any],
-                      pre: Dict[str, float], wall: float,
+                      rec: tracing.Record, wall: float,
                       extra_meta: Optional[Dict[str, Any]] = None,
-                      post: Optional[Dict[str, float]] = None) -> RunResult:
+                      shared: Sequence[tracing.Record] = ()) -> RunResult:
         """Assemble the canonical :class:`RunResult` for one evaluated
         cell.  Both execution engines (the sequential loop and the
         distributed batch engine) MUST go through this, so a cell's
-        record is identical whichever engine produced it.  ``post``
-        bounds the cell's build-accounting window when builds for other
-        cells happened since (the batch engine resolves every cell
-        before simulating any)."""
-        post = post if post is not None else self.stats_snapshot()
+        record is identical whichever engine produced it.  ``rec`` holds
+        the cell's own spans and counters; ``shared`` the records of work
+        the cell shares with others (its ``dist_sweep`` bucket), whose
+        spans and counters the cell's meta carries too."""
+        spans, counts = tracing.totals(rec, *shared)
         meta = {"n_routers": cell.topo.n_routers,
                 "n_endpoints": cell.topo.n_endpoints,
                 "n_flows": int(cell.workload.n_flows),
                 # build-vs-simulate split for this cell's artifacts
-                "build_s": post["build_wall_s"] - pre["build_wall_s"],
-                "build_device_s": (post["build_device_s"]
-                                   - pre["build_device_s"]),
-                "cache_builds": int(post["stack_build"]
-                                    - pre["stack_build"]),
-                "cache_hits": int(post["stack_hit"]
-                                  - pre["stack_hit"]),
+                "build_s": sum((spans[k][0] for k in _BUILD_SPANS.values()
+                                if k in spans), 0.0),
+                "build_device_s": spans.get("stack.device", [0.0])[0],
+                "cache_builds": int(counts.get("stack_build", 0)),
+                "cache_hits": int(counts.get("stack_hit", 0)),
+                "spans": spans, "counts": counts,
                 **table_meta(cell.bundle), **ev_meta,
                 **(extra_meta or {})}
         return RunResult(

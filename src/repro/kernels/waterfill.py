@@ -214,6 +214,7 @@ def _pallas_waterfill(edges, w, desired, active, cap, *, e_tot: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="waterfill",
     )(edges_p, w_p, d_p, act_p, cap_p)
     return tuple(o[:f, 0] for o in out)
 
