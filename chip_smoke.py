@@ -16,13 +16,16 @@ calls (``repro.experiments.Session`` and ``dist_sweep``).  Phases:
    runs twice in one Session: the first call pays the stack build and
    the compiles (set-up), the second is the run alone.  The fatpaths
    cell is run again, the same way, under REPRO_KERNEL_BACKEND=ref (the
-   jnp water-filling oracle) and must agree with the Pallas run within
-   the kernel-vs-oracle tolerances of tests/test_waterfill.py.
+   jnp water-filling oracle) and must agree with the TPU path (the
+   link-sorted form) within the kernel-vs-oracle tolerances of
+   tests/test_waterfill.py.  Each cell prints the water-filling form it
+   ran.
 3. minplus  — a fatpaths(scheme=ksp) stack on sf(q=5), whose (min, +)
    distances run the semiring kernel: its tables must be loop-free, and
    the dense and block-sparse kernels must equal the jnp oracle bitwise.
 4. grid     — sf(q=5), sf(q=11) x ecmp, fatpaths x permutation through
-   dist_sweep(devices=1), identical (rtol 0) to the sequential engine.
+   dist_sweep(devices=1), identical (rtol 0) to the sequential engine,
+   every cell on the link-sorted water-filling.
 
 ``--four-chips`` runs only phase 4's grid over four cell seeds with
 dist_sweep(devices=4), so every bucket takes the shard_map branch, and
@@ -122,8 +125,17 @@ def timed_cell(session, spec):
         f"fct_p50_us={rr.metrics['fct_p50_us']!r} "
         f"fct_p99_us={rr.metrics['fct_p99_us']!r} "
         f"delivered_bytes={rr.meta['delivered_bytes']!r} "
-        f"finished={rr.metrics['finished']!r}")
+        f"finished={rr.metrics['finished']!r} "
+        f"waterfill={waterfill_form(rr)}")
     return rr
+
+
+def waterfill_form(rr) -> str:
+    """The water-filling form the cell's scan ran, from its counters."""
+    forms = [k.split(".", 1)[1] for k in rr.meta["counts"]
+             if k.startswith("waterfill.")]
+    check(len(forms) == 1, f"{rr.cell_id}: water-filling forms {forms}")
+    return forms[0]
 
 
 @contextlib.contextmanager
@@ -232,6 +244,8 @@ def phase_grid(devices: int, seeds=(0,), grid=None) -> None:
     diffs = compare_results(seq, dist)
     check(diffs == [], f"dist_sweep(devices={devices}) != sequential: "
           f"{diffs[:5]}")
+    forms = {waterfill_form(rr) for rr in seq + dist}
+    check(forms == {"sorted"}, f"grid water-filling forms {forms}")
     say(f"# dist_sweep(devices={devices}) == sequential at rtol 0: "
         f"{len(dist)} cells (sequential {t1 - t0:.3f}s, "
         f"dist {t2 - t1:.3f}s, both cold)")

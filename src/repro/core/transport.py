@@ -31,8 +31,8 @@ incast (all-to-one) and concentration effects are captured.
 Execution structure (PR 5):
 
 * **Fused water-filling step** — the per-step scatter/gather/min inner
-  loop is one :func:`repro.kernels.waterfill.waterfill_step` call: a
-  single fused Pallas kernel on TPU, the jnp oracle on CPU
+  loop is one :func:`repro.kernels.waterfill.waterfill_step` call: on
+  TPU the link-sorted form, the jnp oracle on CPU
   (``SimConfig.kernel_backend`` / ``REPRO_KERNEL_BACKEND`` override).
 * **PRNG derivation** — per-flow keys ``fold_in(key, flow)`` are hoisted
   out of the step body; step draws come from
@@ -86,7 +86,7 @@ import numpy as np
 
 from .. import tracing
 from ..kernels import kernel_backend
-from ..kernels.waterfill import waterfill_step
+from ..kernels.waterfill import waterfill_path, waterfill_step
 from .layers import LayeredRouting
 from .topology import Topology
 from .traffic import FlowWorkload
@@ -1040,13 +1040,22 @@ def static_config(cfg: SimConfig) -> SimConfig:
         cfg, seed=0, kernel_backend=cfg.kernel_backend or kernel_backend())
 
 
+def count_waterfill_path(cfg: SimConfig) -> None:
+    """Count the water-filling implementation a scan under ``cfg`` (its
+    :func:`static_config`) runs, ``waterfill.ref`` or
+    ``waterfill.sorted``, once per simulated cell."""
+    tracing.count("waterfill." + waterfill_path(cfg.kernel_backend))
+
+
 def simulate(topo: Topology, routing: LayeredRouting, wl: FlowWorkload,
              cfg: SimConfig) -> SimResult:
     """Run the flow simulator; returns per-flow FCTs and aggregates."""
     jarrs, static = prepare(topo, routing, wl, cfg)
+    scfg = static_config(cfg)
+    count_waterfill_path(scfg)
     with tracing.span("scan"):
         final = jax.block_until_ready(_run_scan(
-            jarrs, jax.random.PRNGKey(cfg.seed), static_config(cfg), static))
+            jarrs, jax.random.PRNGKey(cfg.seed), scfg, static))
     return _to_result(np.asarray(jarrs["size"]), final, cfg,
                       start=np.asarray(jarrs["start"]))
 
@@ -1062,9 +1071,11 @@ def simulate_seeds(topo: Topology, routing: LayeredRouting, wl: FlowWorkload,
         return []
     jarrs, static = prepare(topo, routing, wl, cfg)
     keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+    scfg = static_config(cfg)
+    count_waterfill_path(scfg)
     with tracing.span("scan"):
         finals = jax.block_until_ready(
-            _run_scan_batch(jarrs, keys, static_config(cfg), static))
+            _run_scan_batch(jarrs, keys, scfg, static))
     size = np.asarray(jarrs["size"])
     start = np.asarray(jarrs["start"])
     return [

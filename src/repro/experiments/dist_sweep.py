@@ -276,6 +276,9 @@ def _dispatch_bucket(works: List[_Work], rt: Runtime, bucket_index: int):
                 units.append(units[0])
                 key_rows.append(key_rows[0])
 
+        # Counted in the bucket's record, which every member cell's
+        # meta carries: once per cell.
+        transport_mod.count_waterfill_path(cfg0)
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *units)
         keys = jnp.asarray(np.stack([np.asarray(k) for k in key_rows]))
 

@@ -7,10 +7,10 @@
                         per-tile occupancy bitmaps skip empty blocks,
                         bit-identical to the dense kernel (empty tiles
                         contribute the additive identity exactly).
-* ``waterfill``       — fused max-min water-filling transport step
-                        (§7.1.3): one kernel per simulator step covering
+* ``waterfill``       — max-min water-filling transport step (§7.1.3):
                         the path-edge scatter, fair-share gather, hop-min
-                        and every refinement iteration.
+                        and every refinement iteration by link-sorted
+                        segments in XLA (no Pallas kernel).
 * ``pathcount``       — historical entry point, now the ``"count"``
                         instance of the semiring engine.
 * ``gfmm``            — GF(p) modular matmul, Cheung connectivity (App. B.3).

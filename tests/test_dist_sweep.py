@@ -28,6 +28,18 @@ def test_pad_prepared_is_bitwise_exact():
     """A cell simulated standalone == the same cell padded (flows, links,
     hop slots) and run inside a vmapped batch — bit for bit, every
     SimResult field.  This is the invariant the whole engine rests on."""
+    _assert_padding_exact(None)
+
+
+def test_pad_prepared_is_bitwise_exact_on_the_tpu_path():
+    """The same invariant on the TPU's water-filling (the link-sorted
+    form, run by XLA on the CPU): padding adds inactive flows, trash
+    slots and idle links, which leave every real link's segment and its
+    summation order as they were."""
+    _assert_padding_exact("pallas")
+
+
+def _assert_padding_exact(kernel_backend):
     import jax
     import jax.numpy as jnp
 
@@ -37,7 +49,8 @@ def test_pad_prepared_is_bitwise_exact():
     topo = s.topology("clique(k=6)")
     bundle = s.routing("clique(k=6)", "fatpaths(n_layers=3)")
     wl = s.workload("clique(k=6)", "uniform")
-    cfg = TP.SimConfig(balancing=bundle.balancing, n_steps=50)
+    cfg = TP.SimConfig(balancing=bundle.balancing, n_steps=50,
+                       kernel_backend=kernel_backend)
     base = TP.simulate(topo, bundle.routing, wl, cfg)
 
     arrs, static = TP.prepare(topo, bundle.routing, wl, cfg)
@@ -92,6 +105,24 @@ def test_dist_sweep_matches_sequential_cell_for_cell():
     dist = dist_sweep(s, cells, devices=1)
     assert [r.cell_id for r in dist] == [c.cell_id for c in cells]
     assert compare_results(seq, dist) == []
+
+
+def test_dist_sweep_matches_sequential_on_the_tpu_path(monkeypatch):
+    """dist_sweep pads each cell to its bucket's flows, links and hop
+    slots; on the TPU's water-filling (the link-sorted form, run by XLA
+    on the CPU) every cell still equals its sequential run bit for
+    bit, and each counts that form once."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    seq = Session().sweep(**GRID)
+    s = Session()
+    logs = []
+    dist = dist_sweep(s, s.grid(**GRID), devices=1, log=logs.append)
+    assert compare_results(seq, dist) == []
+    assert any(" programs via " in m for m in logs)
+    for rr in seq + dist:
+        forms = {k: v for k, v in rr.meta["counts"].items()
+                 if k.startswith("waterfill.")}
+        assert forms == {"waterfill.sorted": 1}, rr.cell_id
 
 
 def test_dist_sweep_seed_sweep_shares_operands():

@@ -99,7 +99,7 @@ def test_want_util_kernel_matches_oracle(f, s, e, fair_iters):
     edges, w, desired, cap = _instance(f, s, e, seed=f + s + e)
     sent, share, util = waterfill_step(
         edges, w, desired, cap, fair_iters=fair_iters, backend="pallas",
-        interpret=True, want_util=True)
+        want_util=True)
     sent_r, share_r, util_r = ref.waterfill_ref(
         edges, w, desired, cap, fair_iters=fair_iters, want_util=True)
     np.testing.assert_allclose(np.asarray(sent), np.asarray(sent_r),
@@ -114,8 +114,7 @@ def test_want_util_kernel_matches_oracle(f, s, e, fair_iters):
     assert (u[np.asarray(w) == 0] == 0).all()
     # the lane must not change the base outputs (bitwise, per backend)
     s0, sh0 = waterfill_step(edges, w, desired, cap,
-                             fair_iters=fair_iters, backend="pallas",
-                             interpret=True)
+                             fair_iters=fair_iters, backend="pallas")
     np.testing.assert_array_equal(np.asarray(sent), np.asarray(s0))
     np.testing.assert_array_equal(np.asarray(share), np.asarray(sh0))
     s1, sh1 = ref.waterfill_ref(edges, w, desired, cap,
@@ -134,7 +133,7 @@ def test_want_util_with_active_mask():
     active = jnp.asarray(rng.random(f) < 0.6)
     sent, share, util = waterfill_step(
         edges, w, desired, cap, active=active, backend="pallas",
-        interpret=True, want_util=True)
+        want_util=True)
     sent_r, share_r, util_r = ref.waterfill_ref(
         edges, w, desired, cap, active=active, want_util=True)
     np.testing.assert_allclose(np.asarray(sent), np.asarray(sent_r),

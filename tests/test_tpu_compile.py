@@ -1,11 +1,12 @@
-"""The main path's Pallas kernels compile for a TPU v5e chip.
+"""The main path's kernels compile for a TPU v5e chip.
 
 Nothing runs: each kernel is lowered and compiled for a *described*
 ``v5e:2x2`` topology by the TPU compiler installed next to jax, so a
 kernel that only works in interpret mode (a block shape off the (8, 128)
 tiling, a dynamic lane slice, more VMEM than a kernel may use) fails
 here instead of on the chip.  The waterfill shapes are the sf(q=19)
-permutation cell's: 10,830 flows over 42,599 virtual links.
+permutation cells': 10,830 flows over 42,599 virtual links.  The
+water-filling step is the link-sorted form, plain XLA with no kernel.
 
 The topology is described inside a module-scoped fixture, never at
 import, so collecting this module (every xdist worker does) loads no
@@ -27,7 +28,8 @@ from repro.kernels.waterfill import waterfill_step
 
 SF19_FLOWS = 10_830          # sf(q=19) permutation: one flow per endpoint
 SF19_LINKS = 42_599          # 20,938 fabric + 2 x 10,830 NIC + trash
-SF19_SLOTS = 8               # hop slots + injection + ejection NIC
+SF19_FATPATHS_SLOTS = 9      # hop slots + injection + ejection NIC
+SF19_ECMP_SLOTS = 4
 
 
 @pytest.fixture(scope="module")
@@ -55,19 +57,25 @@ def _compiled_text(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("want_util", [False, True])
-def test_waterfill_compiles_at_sf19_permutation(one_chip, want_util):
-    f, s, e = SF19_FLOWS, SF19_SLOTS, SF19_LINKS
+@pytest.mark.parametrize("slots,want_util", [
+    pytest.param(SF19_FATPATHS_SLOTS, False, id="False"),
+    pytest.param(SF19_FATPATHS_SLOTS, True, id="True"),
+    pytest.param(SF19_ECMP_SLOTS, False, id="ecmp-False"),
+    pytest.param(SF19_ECMP_SLOTS, True, id="ecmp-True")])
+def test_waterfill_compiles_at_sf19_permutation(one_chip, slots, want_util):
+    """The FatPaths (9 slots) and ECMP (4 slots) cells' step, with and
+    without the ECN lane: it compiles, and no kernel call is left."""
+    f, e = SF19_FLOWS, SF19_LINKS
 
     def step(edges, w, desired, cap, active):
         return waterfill_step(edges, w, desired, cap, active=active,
-                              backend="pallas", interpret=False,
-                              want_util=want_util)
+                              backend="pallas", want_util=want_util)
 
-    text = _compiled_text(step, ((f, s), jnp.int32), ((f,), jnp.float32),
-                          ((f,), jnp.float32), ((e,), jnp.float32),
-                          ((f,), jnp.bool_), sharding=one_chip)
-    assert "tpu_custom_call" in text
+    text = _compiled_text(step, ((f, slots), jnp.int32),
+                          ((f,), jnp.float32), ((f,), jnp.float32),
+                          ((e,), jnp.float32), ((f,), jnp.bool_),
+                          sharding=one_chip)
+    assert "tpu_custom_call" not in text
 
 
 @pytest.mark.parametrize("kernel", [semiring_matmul, sparse_semiring_matmul],

@@ -157,6 +157,19 @@ def test_sequential_and_dist_sweep_still_agree():
         assert rr.wall_s >= spans["cell"][0]
 
 
+def test_cells_count_their_waterfill_form(fatpaths_cell):
+    """Each simulated cell counts the water-filling form its scan ran,
+    once, alone and in a dist_sweep bucket (here the CPU's oracle)."""
+    assert fatpaths_cell.meta["counts"]["waterfill.ref"] == 1
+    s = Session()
+    bat = dist_sweep(s, s.grid([CELL[0]], ["ecmp", FATPATHS], [CELL[1]],
+                               [CELL[2]], seeds=[2]))
+    for rr in bat:
+        forms = {k: v for k, v in rr.meta["counts"].items()
+                 if k.startswith("waterfill.")}
+        assert forms == {"waterfill.ref": 1}, rr.cell_id
+
+
 def test_profile_holds_the_spans_on_the_same_clock(tmp_path, fatpaths_cell):
     from jax.profiler import ProfileData
 

@@ -1,8 +1,9 @@
-"""Fused water-filling transport step: Pallas kernel (interpret=True) vs
-jnp oracle, feasibility invariants, and the adaptive scan horizon's
+"""Water-filling transport step: the TPU path (the link-sorted form) vs
+the jnp oracle, feasibility invariants, and the adaptive scan horizon's
 early-exit == full-horizon guarantee."""
 
 import dataclasses
+import functools
 import os
 
 import numpy as np
@@ -15,11 +16,13 @@ except ImportError:  # property tests skip; the rest still run
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.kernels import kernel_backend, ref
-from repro.kernels.waterfill import waterfill_step
+from repro.kernels import waterfill as WF
+from repro.kernels.waterfill import waterfill_path, waterfill_step
 
-# Ragged (F, S, E) instances: tile multiples AND odd remainders in both
-# the flow and link grid dimensions (kernel tiles are bf=128, be=512).
+# Ragged (F, S, E) instances: flow and link counts at and off multiples
+# of 128 and 512, odd slot counts, a single flow.
 SHAPES = [(7, 3, 19), (128, 7, 512), (200, 7, 751), (1, 5, 33),
           (130, 9, 513), (256, 4, 1024)]
 
@@ -41,8 +44,7 @@ def _instance(f, s, e, seed, idle_frac=0.25):
 def test_kernel_matches_oracle(f, s, e, fair_iters):
     edges, w, desired, cap = _instance(f, s, e, seed=f * s + e)
     sent, share = waterfill_step(edges, w, desired, cap,
-                                 fair_iters=fair_iters, backend="pallas",
-                                 interpret=True)
+                                 fair_iters=fair_iters, backend="pallas")
     sent_r, share_r = ref.waterfill_ref(edges, w, desired, cap,
                                         fair_iters=fair_iters)
     np.testing.assert_allclose(np.asarray(sent), np.asarray(sent_r),
@@ -64,8 +66,7 @@ def test_active_lane_matches_oracle_and_masking(f, s, e):
     edges = jnp.asarray(edges)
     active = jnp.asarray(rng.random(f) < 0.6)
     sent_k, share_k = waterfill_step(edges, w, desired, cap,
-                                     active=active, backend="pallas",
-                                     interpret=True)
+                                     active=active, backend="pallas")
     sent_r, share_r = ref.waterfill_ref(edges, w, desired, cap,
                                         active=active)
     np.testing.assert_allclose(np.asarray(sent_k), np.asarray(sent_r),
@@ -100,11 +101,127 @@ def test_never_oversubscribes(backend):
         edges, w, desired, cap = _instance(160, 6, 301, seed=seed,
                                            idle_frac=0.1)
         sent, _ = waterfill_step(edges, w, desired, cap, fair_iters=2,
-                                 backend=backend, interpret=True)
+                                 backend=backend)
         load = _link_load(edges, sent, 301)
         assert (load <= np.asarray(cap) + 1e-4).all(), load.max()
         # and sends never exceed what was asked for
         assert (np.asarray(sent) <= np.asarray(desired) + 1e-6).all()
+
+
+def _lane_instance(lane, f=130, s=9, e=513, seed=11):
+    """Inputs of one step exercising ``lane``: raw -1 padding under a
+    mixed active mask in every case, plus links at capacity 0
+    (``dead``, link death and churn) or rows with no live slot at all
+    (``all_trash``: active flows whose slots are all -1 or trash)."""
+    edges, w, desired, cap = _instance(f, s, e, seed=seed, idle_frac=0.0)
+    rng = np.random.default_rng(seed)
+    edges = np.array(edges)
+    edges[rng.random((f, s)) < 0.2] = -1
+    active = rng.random(f) < 0.7
+    cap = np.array(cap)
+    if lane == "dead":
+        cap[:-1][rng.random(e - 1) < 0.15] = 0.0
+    if lane == "all_trash":
+        edges[::5] = -1
+        edges[1::7] = e - 1
+        active[::5] = True
+    return (jnp.asarray(edges), w, desired, jnp.asarray(cap),
+            jnp.asarray(active))
+
+
+def _assert_step_close(got, want):
+    """Kernel-vs-oracle tolerances; +inf shares must match exactly."""
+    for g, r in zip(got, want):
+        g, r = np.asarray(g), np.asarray(r)
+        fin = np.isfinite(r)
+        np.testing.assert_array_equal(np.isfinite(g), fin)
+        np.testing.assert_array_equal(g[~fin], r[~fin])
+        np.testing.assert_allclose(g[fin], r[fin], rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("lane", ["want_util", "dead", "all_trash", "vmap"])
+@pytest.mark.parametrize("fair_iters", [0, 1, 2])
+def test_lanes_match_oracle(lane, fair_iters):
+    """The TPU form against the oracle on the scan's lanes: the ECN
+    lane's util output, capacity-0 links, rows with no live slot, and a
+    vmap-batched call (the scan runs the step under vmap)."""
+    want_util = lane in ("want_util", "vmap")
+    step = functools.partial(waterfill_step, fair_iters=fair_iters,
+                             backend="pallas", want_util=want_util)
+    oracle = functools.partial(ref.waterfill_ref, fair_iters=fair_iters,
+                               want_util=want_util)
+    if lane == "vmap":
+        batch = [_lane_instance("dead", seed=k) for k in range(3)]
+        args = [jnp.stack(x) for x in zip(*batch)]
+        got = jax.vmap(lambda e, w, d, c, a: step(e, w, d, c, active=a))(
+            *args)
+        for k, inst in enumerate(batch):
+            _assert_step_close([g[k] for g in got],
+                               oracle(*inst[:4], active=inst[4]))
+        return
+    edges, w, desired, cap, active = _lane_instance(lane)
+    got = step(edges, w, desired, cap, active=active)
+    want = oracle(edges, w, desired, cap, active=active)
+    _assert_step_close(got, want)
+    if lane == "all_trash":
+        dark = np.asarray((edges < 0) | (edges == edges.max())).all(axis=1)
+        assert dark.any()
+        assert np.isposinf(np.asarray(got[1])[dark]).all()
+        if fair_iters:              # no link to scale by: sends nothing
+            assert (np.asarray(got[0])[dark] == 0).all()
+    if lane == "dead":
+        # a flow crossing a dead link sends nothing
+        e = np.asarray(edges)
+        hit = (np.asarray(cap)[e] == 0) & (e >= 0)
+        hit = hit.any(axis=1) & np.asarray(active)
+        assert hit.any() and (np.asarray(got[0])[hit] == 0).all()
+
+
+@pytest.mark.parametrize("bound", [64, 2**30], ids=["folded", "rows"])
+def test_batched_sort_matches_row_sorts(bound):
+    """The sorted form's sort under (nested) vmap: the batch folded into
+    the key, or, where the folded key would overflow int32, a sort of
+    the rows; either way each row is the row's own stable sort."""
+    rng = np.random.default_rng(3)
+    key = jnp.asarray(rng.integers(0, 40, (2, 3, 50)).astype(np.int32))
+    val = jnp.asarray(rng.random((2, 3, 50)).astype(np.float32))
+    sort = functools.partial(WF._sort, bound=bound, stable=True)
+    got = jax.vmap(jax.vmap(lambda k, v: sort(k, (v,))))(key, val)
+    for i in range(2):
+        for j in range(3):
+            want = jax.lax.sort((key[i, j], val[i, j]), num_keys=1,
+                                is_stable=True)
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(g[i, j]),
+                                              np.asarray(w))
+
+
+def _traced(f, s, e, backend):
+    shapes = [((f, s), jnp.int32), ((f,), jnp.float32),
+              ((f,), jnp.float32), ((e,), jnp.float32), ((f,), jnp.bool_)]
+    return str(jax.make_jaxpr(
+        lambda edges, w, d, cap, act: waterfill_step(
+            edges, w, d, cap, active=act, backend=backend))(
+        *[jax.ShapeDtypeStruct(sh, dt) for sh, dt in shapes]))
+
+
+@pytest.mark.parametrize("f,s,e", [
+    (200, 8, 751),          # sf(q=5) FatPaths
+    (200, 4, 751),          # sf(q=5) ECMP
+    (10_830, 9, 42_599),    # sf(q=19) FatPaths
+    (10_830, 4, 42_599),    # sf(q=19) ECMP
+])
+@pytest.mark.parametrize("backend,path", [("pallas", "sorted"),
+                                          ("ref", "ref")])
+def test_backend_picks_the_form(f, s, e, backend, path):
+    """The TPU backend runs the link-sorted form at every size, with no
+    kernel call and no scatter; the oracle is XLA's scatter and gather.
+    Read off the traced program."""
+    assert waterfill_path(backend) == path
+    jaxpr = _traced(f, s, e, backend)
+    assert "pallas_call" not in jaxpr
+    assert (" sort" in jaxpr) == (path == "sorted")
+    assert ("scatter" in jaxpr) == (path == "ref")
 
 
 @settings(max_examples=20, deadline=None)
@@ -135,14 +252,19 @@ def _tiny_cell(balancing="fatpaths", topo_spec="clique(k=6)"):
 
 @pytest.mark.parametrize("transport", ["ndp", "tcp", "dctcp"])
 def test_sim_kernel_backend_parity(transport):
-    """The full simulator agrees between the fused Pallas step
-    (interpret=True on CPU) and the jnp oracle, for every transport."""
+    """The full simulator agrees between the TPU path (the link-sorted
+    form) and the jnp oracle, for every transport; each cell counts the
+    path it took."""
     TP, topo, bundle, wl = _tiny_cell()
     mk = lambda be: TP.SimConfig(  # noqa: E731
         transport=transport, balancing=bundle.balancing, n_steps=30,
         kernel_backend=be)
-    res_ref = TP.simulate(topo, bundle.routing, wl, mk("ref"))
-    res_pl = TP.simulate(topo, bundle.routing, wl, mk("pallas"))
+    with tracing.record() as rec_ref:
+        res_ref = TP.simulate(topo, bundle.routing, wl, mk("ref"))
+    with tracing.record() as rec_pl:
+        res_pl = TP.simulate(topo, bundle.routing, wl, mk("pallas"))
+    assert rec_ref.counts["waterfill.ref"] == 1
+    assert rec_pl.counts["waterfill.sorted"] == 1
     np.testing.assert_allclose(res_pl.fct, res_ref.fct, rtol=1e-5)
     np.testing.assert_allclose(res_pl.delivered, res_ref.delivered,
                                rtol=1e-4)
