@@ -1,5 +1,7 @@
-"""cell_s: window seconds per whole ``Session.run`` cell, the wall a user
-waits from spec to ``RunResult`` (a fresh Session each time)."""
+"""cell_s: the window's seconds over the whole ``Session.run`` cells it
+completed, each on a fresh Session: the mean wall a user waits from spec
+to ``RunResult``, taken over all the work and all the time of the window
+(which starts after set-up and ends with the last whole iteration)."""
 
 
 def read(run):

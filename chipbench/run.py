@@ -16,12 +16,14 @@ start of the window): import, the compile cache, the device check, and
 one whole warm-up iteration through the same entry and seed as the
 window, which compiles every program the window runs.  The window then
 starts whole iterations until ``--seconds`` have passed and ends with
-the last one.  With ``--trace 1`` the window is profiled, each
-iteration and each layer call is wrapped in a ``chipbench.*`` span, and
-the per-layer metrics are read from the trace and the iterations'
-records; without it the end-to-end metrics are printed.  After the
-window the last iteration's outputs are compared with the plain
-reference (``chipbench/check.py``).
+the last one; it records each iteration's wall, and every iteration
+counts in the metrics (``cell_s`` is the window's seconds over all its
+cells).  With ``--trace 1`` the window is profiled, each iteration and
+each layer call is wrapped in a ``chipbench.*`` span, and the per-layer
+metrics are read from the trace and the iterations' records; without
+it the end-to-end metrics are printed.  After the window the last
+iteration's outputs are compared with the plain reference
+(``chipbench/check.py``).
 
 Earlier lines of standard output describe each iteration; the last line
 is the JSON result.  The last lines of standard error are the numbers

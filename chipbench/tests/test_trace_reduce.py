@@ -61,3 +61,15 @@ def test_idle_gaps_named_by_the_innermost_span():
                     ["iteration", pytest.approx(10e-9)]]
     t.spans = []
     assert tr.idle_gaps(t)[0][0] == "untraced"
+
+
+def test_ops_count_in_the_module_they_start_in_on_their_own_chip():
+    t = _trace()
+    # chip 0's fusions start inside its scan module; chip 1's fusion
+    # starts before chip 1's scan module and does not count
+    assert tr.op_seconds_in_modules(t, r"^fusion", r"scan") == \
+        pytest.approx((20 + 15) / 1e9)
+    assert tr.op_seconds_in_modules(t, r"^copy", r"path_edge") == \
+        pytest.approx(10 / 1e9)
+    assert tr.op_seconds_in_modules(t, r"^fusion", r"path_edge") is None
+    assert tr.op_seconds_in_modules(t, r"^copy", r"scan") is None

@@ -10,6 +10,9 @@ after that works on those lists:
   busy over the window;
 * a module's device time is the sum of its events' durations, matched
   by a regular expression on the module name;
+* an operation's device time within a module is the sum of the
+  durations of the operations matched by their HLO instruction name
+  that start inside such a module's interval on the same chip;
 * each idle gap (between merged busy intervals of the first chip used)
   is attributed to the innermost benchmark span that holds its midpoint.
 
@@ -18,6 +21,7 @@ Times are in nanoseconds inside, seconds outside.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
@@ -25,8 +29,8 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["Trace", "from_xplane", "find_xplane", "union_ns", "busy_s",
-           "window_s", "idle_pct", "module_seconds", "top_modules",
-           "idle_gaps", "SPAN_PREFIX"]
+           "window_s", "idle_pct", "module_seconds", "op_seconds_in_modules",
+           "top_modules", "idle_gaps", "SPAN_PREFIX"]
 
 SPAN_PREFIX = "chipbench."
 Interval = Tuple[str, float, float]          # (name, start_ns, end_ns)
@@ -148,6 +152,33 @@ def module_seconds(trace: Trace, pattern: str) -> Optional[float]:
     if not hits:
         return None
     return sum(b - a for _, a, b in hits) / 1e9
+
+
+def op_seconds_in_modules(trace: Trace, op_pattern: str,
+                          module_pattern: str) -> Optional[float]:
+    """Device seconds of the operations whose HLO instruction name (the
+    event name's part before `` = ``) matches ``op_pattern`` and that
+    start inside a module whose name matches ``module_pattern`` on the
+    same chip, clipped to the window and summed over the chips used;
+    None when no such operation ran in the window."""
+    op_rx, mod_rx = re.compile(op_pattern), re.compile(module_pattern)
+    lo, hi = trace.window
+    total, hit = 0.0, False
+    for mods, ops in zip(trace.modules, trace.ops):
+        # one chip runs one module at a time: intervals do not overlap
+        held = sorted((a, b) for n, a, b in mods
+                      if mod_rx.search(_module_name(n)))
+        starts = [a for a, _ in held]
+        for name, a, b in ops:
+            if not op_rx.search(name.split(" = ", 1)[0]):
+                continue
+            i = bisect.bisect_right(starts, a) - 1
+            if i < 0 or a >= held[i][1]:
+                continue
+            d = min(b, hi) - max(a, lo)
+            if d > 0:
+                total, hit = total + d, True
+    return total / 1e9 if hit else None
 
 
 def top_modules(trace: Trace, k: int = 10) -> List[List]:
