@@ -150,7 +150,9 @@ def padded_signature(cfg, n_layers: int, n_flows: int, e_tot: int,
 # elements — operands per element; ``_*_scan_seeds`` batches cells with
 # a NESTED vmap over each cell's sim-seed keys, so a seed sweep shares
 # one copy of the cell's operand tensors instead of shipping
-# ``n_seeds`` duplicates to the device.
+# ``n_seeds`` duplicates to the device.  The functions the ``_sharded_*``
+# variants wrap keep ``scan`` in their names: they name the XLA module
+# (``jit_sweep_scan``), which is how a device trace finds the scan.
 @functools.partial(jax.jit, static_argnames=("cfg", "static"))
 def _vmapped_scan(stacked, keys, cfg, static):
     return jax.vmap(
@@ -169,10 +171,10 @@ def _vmapped_scan_seeds(stacked, keys, cfg, static):
 def _sharded_scan(rt: Runtime, cfg, static):
     axis = rt.data_axes[0]
 
-    def body(stacked, keys):
+    def sweep_scan(stacked, keys):
         return _vmapped_scan(stacked, keys, cfg, static)
 
-    return jax.jit(rt.shard_map(body, in_specs=(P(axis), P(axis)),
+    return jax.jit(rt.shard_map(sweep_scan, in_specs=(P(axis), P(axis)),
                                 out_specs=P(axis)))
 
 
@@ -180,10 +182,11 @@ def _sharded_scan(rt: Runtime, cfg, static):
 def _sharded_scan_seeds(rt: Runtime, cfg, static):
     axis = rt.data_axes[0]
 
-    def body(stacked, keys):
+    def sweep_scan_seeds(stacked, keys):
         return _vmapped_scan_seeds(stacked, keys, cfg, static)
 
-    return jax.jit(rt.shard_map(body, in_specs=(P(axis), P(axis)),
+    return jax.jit(rt.shard_map(sweep_scan_seeds,
+                                in_specs=(P(axis), P(axis)),
                                 out_specs=P(axis)))
 
 

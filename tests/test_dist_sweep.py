@@ -155,6 +155,51 @@ def test_dist_sweep_mixed_evaluators_fall_back():
     assert [r.evaluator for r in dist] == ["transport(steps=40)", "mat"]
 
 
+@pytest.mark.parametrize("grid,module", [
+    (GRID, "jit_sweep_scan"),
+    (dict(GRID, routings=["fatpaths(n_layers=3)"],
+          evaluators=["transport(steps=40,seeds=3)"], seeds=[0]),
+     "jit_sweep_scan_seeds"),
+], ids=["flat", "seednest"])
+def test_sharded_bucket_programs_are_named_scan(grid, module, monkeypatch):
+    """The sharded bucket programs' XLA modules carry ``scan`` in their
+    names (``jit_sweep_scan``, ``jit_sweep_scan_seeds``), which is how a
+    device trace finds the sweep's scan; on a one-device mesh the
+    ``shard_map`` path runs and still equals the sequential engine."""
+    import jax
+    from jax.sharding import Mesh
+
+    from repro.dist import Runtime
+    from repro.experiments import dist_sweep as ds
+
+    names = []
+
+    def spy(make):
+        def sharded(rt, cfg, static):
+            prog = make(rt, cfg, static)
+
+            def call(stacked, keys):
+                hlo = prog.lower(stacked, keys).as_text(dialect="hlo")
+                names.append(hlo.split(None, 2)[1].rstrip(","))
+                return prog(stacked, keys)
+            return call
+        return sharded
+
+    monkeypatch.setattr(ds, "_sharded_scan", spy(ds._sharded_scan))
+    monkeypatch.setattr(ds, "_sharded_scan_seeds",
+                        spy(ds._sharded_scan_seeds))
+    rt = Runtime(mesh=Mesh(np.asarray(jax.devices()[:1]), ("data",)))
+    seq = Session().sweep(**grid)
+    s = Session()
+    logs = []
+    dist = dist_sweep(s, s.grid(**grid), runtime=rt, log=logs.append)
+    assert compare_results(seq, dist) == []
+    buckets = [m for m in logs if " programs via " in m]
+    assert buckets and all("shard_map[1]" in m for m in buckets)
+    assert len(names) == len(buckets)
+    assert set(names) == {module}, names
+
+
 def test_sweep_devices_kwarg_routes_to_engine():
     got = Session().sweep(devices=1, **GRID)
     seq = Session().sweep(**GRID)
